@@ -637,6 +637,9 @@ def main(argv=None) -> None:
                     help="run the precision sweep (fp32/bf16/fp16/int8) and "
                          "emit the accuracy-vs-speed JSON (BENCH_quant.json)")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
     if args.json:
         emit_json(args.json, config=args.config)
         return

@@ -10,11 +10,13 @@ strides > 2). Passing an explicit autotuner ``choice`` (a
 kernel parameters (``block_k``/``block_h``/``block_c``) — this is how a
 TuningPlan's per-layer decisions reach the kernels.
 
-Stride 2 stays in-kernel for every family: ilpm/direct slide strided tap
-windows over the resident image (the ResNet 7x7/2 stem and stage-entry
-3x3/2s), pointwise subsamples in-kernel (1x1/2 projection shortcuts), and
-depthwise always downsampled in-kernel. Only im2col/libdnn/winograd are
-stride-1-only; forcing one of them on a strided site falls back to ilpm.
+Stride 2 stays in a Pallas kernel for every family: ilpm slides tap
+windows over the resident image's stride phases and direct over strided
+row bands (the ResNet 7x7/2 stem and stage-entry 3x3/2s), pointwise runs
+on the subsampled image (1x1/2 projection shortcuts), and depthwise
+downsamples in-kernel. Only
+im2col/libdnn/winograd are stride-1-only; forcing one of them on a strided
+site falls back to ilpm.
 
 The optional fused epilogue — ``scale``/``bias`` (folded BatchNorm, (K,)
 vectors) and ``act`` ('relu' | 'relu6') — is threaded through dispatch into
@@ -38,7 +40,7 @@ from repro.core import autotune
 from repro.core.convspec import ConvSpec
 from repro.kernels import ops, ref
 
-# kernels that downsample in-kernel (strided tap windows / subsampling)
+# dense kernels with a stride-2 variant
 STRIDED_DENSE = ("ilpm", "direct")
 
 

@@ -74,8 +74,8 @@ def tunable(spec: ConvSpec) -> bool:
       * dense spatial convs — the paper's five contenders at stride 1,
         the strided ilpm/direct variants at stride 2;
       * depthwise convs (groups == c, k = M·c for multiplier M >= 1);
-      * dense 1x1 convs — the pointwise kernel (in-kernel subsample at
-        stride 2).
+      * dense 1x1 convs — the pointwise kernel (on the subsampled image
+        at stride 2).
 
     Everything else (grouped non-depthwise convs, strides > 2) runs on the
     XLA reference path; such sites still get a plan entry with an ``xla``

@@ -266,10 +266,9 @@ class InferenceEngine:
 
     def trace_count(self):
         """Number of distinct shapes the batch forward has been traced
-        for (None if this jax version doesn't expose it) — the serving
-        tests use it to prove padded buckets bound retraces."""
-        size = getattr(self._fwd_batch, "_cache_size", None)
-        return size() if callable(size) else None
+        for — the serving tests use it to prove padded buckets bound
+        retraces."""
+        return self._fwd_batch._cache_size()
 
     def traffic_report(self):
         """Per-layer bytes/flops for every planned conv site — the energy

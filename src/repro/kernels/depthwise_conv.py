@@ -11,14 +11,16 @@ C-contraction to feed the MXU. The ILP-M blocking transfers directly:
     each grid step owns a `block_c` channel slab end-to-end (image slab,
     filter slab, output slab all cut on the same axis), so nothing is
     refetched;
-  * static tap loop: each (r, s) step is one strided window load times one
+  * static tap loop: each (r, s) step is one window load times one
     per-channel filter row, `H·W : 1` arithmetic:load on the filter operand
     — the paper's `workgroup_size : 1` ratio, elementwise instead of MXU.
 
 Stride 1 and 2 both run in-kernel (MobileNet downsamples inside its
-depthwise layers). Channel multipliers > 1 are supported with lax's HWIO
-convention — filters (R, S, 1, M·C), output channel k reading input channel
-k // M — by repeating the input slab M× on lanes inside the kernel. An
+depthwise layers): the slab is held in its stride phases
+(``phases.split``), so every tap window is a contiguous slice. Channel
+multipliers > 1 are supported with lax's HWIO convention — filters
+(R, S, 1, M·C), output channel k reading input channel k // M — by
+repeating the input slab M× on lanes inside the kernel. An
 optional (scale, bias, act) epilogue folds BN + ReLU6 into the output
 write, same contract as the dense kernels.
 """
@@ -30,23 +32,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import phases
 from repro.kernels.fusion import epilogue_operands
 from repro.kernels.ref import apply_act
 
 
 def _kernel(x_ref, w_ref, *refs, H, W, R, S, stride, mult, act, fused):
-    """x_ref: (1, Hp, Wp, TC) padded image channel slab, VMEM-pinned.
+    """x_ref: (1, stride², Hq, Wq, TC) padded image channel slab in its
+    stride phases, VMEM-pinned.
     w_ref: (R, S, 1, TK) — the slab's per-channel filter taps (TK = M·TC).
     refs: optional (scale, bias) (1, TK) slabs, then o_ref (1, H, W, TK).
     """
     o_ref = refs[-1]
-    x = x_ref[0]
     TK = w_ref.shape[-1]
     acc = jnp.zeros((H, W, TK), jnp.float32)
     for r in range(R):          # static taps — fully unrolled, VPU-pipelined
         for s in range(S):
-            xs = x[r:r + (H - 1) * stride + 1:stride,
-                   s:s + (W - 1) * stride + 1:stride, :]
+            ph, dr, dc = phases.tap(r, s, stride)
+            xs = x_ref[0, ph, dr:dr + H, dc:dc + W, :]
             if mult > 1:        # channel k convolves input channel k // M
                 xs = jnp.repeat(xs, mult, axis=-1)
             acc += xs.astype(jnp.float32) * w_ref[r, s, 0].astype(jnp.float32)
@@ -80,9 +83,11 @@ def depthwise_conv(x_padded, w, *, stride: int = 1, block_c: int = 128,
     tk = max(mult, tk - tk % mult)  # output slab must hold whole input lanes
     tc = tk // mult
     grid = (B, pl.cdiv(K, tk))
-    operands = [x_padded, w]
+    xq = phases.split(x_padded, stride)
+    operands = [xq, w]
     in_specs = [
-        pl.BlockSpec((1, Hp, Wp, tc), lambda b, c: (b, 0, 0, c)),
+        pl.BlockSpec((1,) + xq.shape[1:4] + (tc,),
+                     lambda b, c: (b, 0, 0, 0, c)),
         pl.BlockSpec((R, S, 1, tk), lambda b, c: (0, 0, 0, c)),
     ]
     fused, extra, extra_specs = epilogue_operands(

@@ -7,8 +7,11 @@ keep the *intermediates between layers* resident too:
 
   * ``fused_inverted_residual`` — MobileNet's expand(1x1) -> depthwise
     (RxS, stride 1|2) -> project(1x1) chain in ONE ``pallas_call``. The
-    expanded tensor (t*Cin wide — the largest activation in the network)
-    is computed, SAME-padded, convolved, and consumed entirely in VMEM;
+    input arrives in its stride phases (``phases.split``), so at stride 2
+    every depthwise tap is a contiguous window of one expanded phase, as
+    in the per-layer strided kernels. The expanded tensor (t*Cin wide —
+    the largest activation in the network) is computed, SAME-padded,
+    convolved, and consumed entirely in VMEM;
     it never touches HBM ("High Performance Depthwise and Pointwise
     Convolutions on Mobile Devices" builds its mobile speedup on exactly
     this fusion). The expanded width is cut into per-channel slabs
@@ -40,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import phases
 from repro.kernels.ref import apply_act
 
 
@@ -65,12 +69,13 @@ def _ir_kernel(x_ref, *refs, H, W, OH, OW, R, S, stride, pads, act, out_act,
                residual, expanded, nm, compute_dtype):
     """One grid step = one expanded-channel slab of one image.
 
-    x_ref: (1, H, W, Cin) — the *unpadded* input, VMEM-resident across the
-    whole mid-slab row (its index map ignores the m axis); also the
-    residual identity. Then, when ``expanded``: w1 (1,1,Cin,TM), s1/b1
-    (1,TM); always: wdw (R,S,1,TM), sdw/bdw (1,TM), w2 (1,1,TM,Cout),
-    s2/b2 (1,Cout), o_ref (1,OH,OW,Cout), and the fp32 (OH*OW, Cout)
-    projection accumulator scratch.
+    x_ref: (1, stride², Hx, Wx, Cin) — the *unpadded* input in its stride
+    phases (``phases.split``), VMEM-resident across the whole mid-slab
+    row (its index map ignores the m axis); at stride 1 also the residual
+    identity. Then, when ``expanded``: w1 (1,1,Cin,TM), s1/b1 (1,TM);
+    always: wdw (R,S,1,TM), sdw/bdw (1,TM), w2 (1,1,TM,Cout), s2/b2
+    (1,Cout), o_ref (1,OH,OW,Cout), and the fp32 (OH*OW, Cout) projection
+    accumulator scratch.
     """
     acc_ref = refs[-1]
     o_ref = refs[-2]
@@ -80,28 +85,45 @@ def _ir_kernel(x_ref, *refs, H, W, OH, OW, R, S, stride, pads, act, out_act,
         wdw, sdw, bdw, w2, s2, b2 = refs[:6]
     m = pl.program_id(1)
     x = x_ref[0]
-    # --- expand: one (H*W, Cin) @ (Cin, TM) MXU step + BN/act epilogue,
-    # cast to the compute dtype exactly where the per-layer pointwise
-    # kernel's output write would cast ---
+    # --- expand: one (pixels, Cin) @ (Cin, TM) MXU step over every phase
+    # + BN/act epilogue, cast to the compute dtype exactly where the
+    # per-layer pointwise kernel's output write would cast (a 1x1 conv is
+    # per-pixel, so expanding the phases is expanding the image) ---
     if expanded:
-        e = jnp.dot(x.reshape(H * W, x.shape[-1]), w1[0, 0],
+        e = jnp.dot(x.reshape(-1, x.shape[-1]), w1[0, 0],
                     preferred_element_type=jnp.float32)
         e = apply_act(e * s1[0] + b1[0], act).astype(compute_dtype)
-        e = e.reshape(H, W, e.shape[-1])
+        e = e.reshape(x.shape[:-1] + e.shape[-1:])
     else:
         e = x  # t == 1: the slab *is* the input (tm == mid == cin)
-    # --- SAME-pad the slab in VMEM: exact zeros, identical to the
-    # per-layer pad_same of the expand output (the expanded tensor's HBM
-    # round-trip this kernel exists to delete) ---
-    (ph_lo, ph_hi), (pw_lo, pw_hi) = pads
-    ep = jnp.pad(e, ((ph_lo, ph_hi), (pw_lo, pw_hi), (0, 0)))
+    # each phase, less the rows and cols split() padded it with
+    e = {(p, q): e[p * stride + q, :-(-(H - p) // stride),
+                   :-(-(W - q) // stride)]
+         for p in range(stride) for q in range(stride)}
+    # --- SAME-pad in VMEM: exact zeros, identical to the per-layer
+    # pad_same of the expand output (the expanded tensor's HBM round-trip
+    # this kernel exists to delete). Phase (a, b) of the padded slab is
+    # input phase ((a - top) % stride, (b - left) % stride) shifted down
+    # and right by the rows and columns of padding that land in it. ---
+    (top, _), (left, _) = pads
+    hq, wq = OH + (R - 1) // stride, OW + (S - 1) // stride
+
+    def padded(a, b):
+        src = e[((a - top) % stride, (b - left) % stride)]
+        i0 = max(0, -(-(top - a) // stride))
+        j0 = max(0, -(-(left - b) // stride))
+        n, k = min(src.shape[0], hq - i0), min(src.shape[1], wq - j0)
+        return jnp.pad(src[:n, :k], ((i0, hq - i0 - n), (j0, wq - j0 - k),
+                                     (0, 0)))
+
+    ep = {(a, b): padded(a, b) for a in range(stride) for b in range(stride)}
     # --- depthwise: static tap loop over the resident padded slab, VPU
     # work, fp32 accumulate, BN/act epilogue, cast-on-"write" (to VMEM) ---
-    d = jnp.zeros((OH, OW, ep.shape[-1]), jnp.float32)
+    d = jnp.zeros((OH, OW, wdw.shape[-1]), jnp.float32)
     for r in range(R):
         for s in range(S):
-            xs = ep[r:r + (OH - 1) * stride + 1:stride,
-                    s:s + (OW - 1) * stride + 1:stride, :]
+            dr, dc = r // stride, s // stride
+            xs = ep[(r % stride, s % stride)][dr:dr + OH, dc:dc + OW, :]
             d += xs.astype(jnp.float32) * wdw[r, s, 0].astype(jnp.float32)
     d = apply_act(d * sdw[0] + bdw[0], act).astype(compute_dtype)
     # --- project: this slab's partial (OH*OW, Cout) contraction ---
@@ -123,7 +145,7 @@ def _ir_kernel(x_ref, *refs, H, W, OH, OW, R, S, stride, pads, act, out_act,
         y = apply_act(y, out_act).astype(o_ref.dtype)
         if residual:
             # the identity is the already-resident input: zero extra HBM
-            y = y + x.reshape(y.shape)
+            y = y + x[0].reshape(y.shape)
         o_ref[0] = y.reshape(OH, OW, y.shape[-1])
 
 
@@ -170,11 +192,12 @@ def fused_inverted_residual(x, weights, *, stride: int = 1,
         tm = mid  # single slab: t == 1 slabs ride the unsliced input
     nm = mid // tm
     grid = (B, nm)
-    operands = [x]
+    xq = phases.split(x, stride)
+    operands = [xq]
     in_specs = [
         # index map ignores m -> the input (and residual identity) stays
         # resident across the whole slab row
-        pl.BlockSpec((1, H, W, Cin), lambda b, m: (b, 0, 0, 0)),
+        pl.BlockSpec((1,) + xq.shape[1:], lambda b, m: (b, 0, 0, 0, 0)),
     ]
     row = pl.BlockSpec((1, tm), lambda b, m: (0, m))
     if expanded:

@@ -10,8 +10,9 @@ degenerate one-tap case of `ilpm_conv`:
     where the traffic win is;
   * one MXU contraction per grid step, no halo and no padding (R=S=1);
   * stride ∈ {1, 2}: strided 1x1 convs (ResNet projection shortcuts at
-    stage entries) subsample the resident image in-kernel — `x[::2, ::2]`
-    against the pinned tile, no XLA gather pass;
+    stage entries) read only every stride-th pixel, subsampled by the
+    wrapper (one XLA strided slice): Mosaic cannot lower a strided read of
+    a tile wider than 128 lanes (see ``phases``);
   * optional fused (scale, bias, act) epilogue in the output write, same
     contract as `ilpm_conv`.
 
@@ -30,15 +31,15 @@ from repro.kernels.fusion import epilogue_operands
 from repro.kernels.ref import apply_act
 
 
-def _kernel(x_ref, w_ref, *refs, H, W, stride, act, fused):
-    """x_ref: (1, Hin, Win, C) — full image, VMEM-pinned.
+def _kernel(x_ref, w_ref, *refs, H, W, act, fused):
+    """x_ref: (1, H, W, C) — full (subsampled) image, VMEM-pinned.
     w_ref: (1, 1, C, TK) — one output-channel slab.
     refs: optional (scale, bias) (1, TK) slabs, then o_ref (1, H, W, TK).
     """
     o_ref = refs[-1]
     C = x_ref.shape[-1]
     TK = w_ref.shape[-1]
-    xs = x_ref[0, ::stride, ::stride, :].reshape(H * W, C)
+    xs = x_ref[0].reshape(H * W, C)
     acc = jnp.dot(xs, w_ref[0, 0], preferred_element_type=jnp.float32)
     if fused:
         acc = acc * refs[0][0] + refs[1][0]
@@ -52,17 +53,17 @@ def pointwise_conv(x, w, *, stride: int = 1, block_k: int = 128,
                    scale=None, bias=None, act=None, interpret: bool = False):
     """x: (B, H, W, C) — no padding needed; w: (1,1,C,K)
     -> (B, ceil(H/stride), ceil(W/stride), K)."""
-    B, H, W, C = x.shape
     R, S, _, K = w.shape
     assert (R, S) == (1, 1), f"pointwise kernel wants 1x1 filters, got {w.shape}"
-    Ho = -(-H // stride)
-    Wo = -(-W // stride)
+    if stride != 1:
+        x = x[:, ::stride, ::stride, :]
+    B, Ho, Wo, C = x.shape
     tk = min(block_k, K)
     grid = (B, pl.cdiv(K, tk))
     operands = [x, w]
     in_specs = [
         # index map ignores k -> image stays resident across the K row
-        pl.BlockSpec((1, H, W, C), lambda b, k: (b, 0, 0, 0)),
+        pl.BlockSpec((1, Ho, Wo, C), lambda b, k: (b, 0, 0, 0)),
         pl.BlockSpec((1, 1, C, tk), lambda b, k: (0, 0, 0, k)),
     ]
     fused, extra, extra_specs = epilogue_operands(
@@ -70,8 +71,7 @@ def pointwise_conv(x, w, *, stride: int = 1, block_k: int = 128,
     operands += extra
     in_specs += extra_specs
     return pl.pallas_call(
-        functools.partial(_kernel, H=Ho, W=Wo, stride=stride, act=act,
-                          fused=fused),
+        functools.partial(_kernel, H=Ho, W=Wo, act=act, fused=fused),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Ho, Wo, tk), lambda b, k: (b, 0, 0, k)),

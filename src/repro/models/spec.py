@@ -7,6 +7,7 @@ no allocation), and ``NamedSharding`` trees (pjit in/out shardings).
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass, field
 
 import jax
@@ -70,7 +71,11 @@ def _walk(tree, path=()):
 
 
 def init_params(spec_tree, seed: int, param_dtype: str):
-    """Materialize a spec tree (CPU-sized configs only)."""
+    """Materialize a spec tree (CPU-sized configs only).
+
+    Each leaf's key folds a CRC of its path names into ``seed``, so the
+    same seed gives the same weights in every process (``hash()`` of a
+    string is salted per process)."""
     root = jax.random.key(seed)
 
     def build(tree):
@@ -82,7 +87,7 @@ def init_params(spec_tree, seed: int, param_dtype: str):
     for path, spec in _walk(spec_tree):
         key = root
         for p in path:
-            key = jax.random.fold_in(key, hash(p) % (2**31))
+            key = jax.random.fold_in(key, zlib.crc32(str(p).encode()) % 2**31)
         node = out
         for p in path[:-1]:
             node = node[p]

@@ -22,19 +22,13 @@ import jax.numpy as jnp
 
 from repro.quant import dequantize, quantize  # noqa: F401  (re-export)
 
-try:  # newer JAX exposes shard_map at the top level (check_vma kwarg)
-    from jax import shard_map as _shard_map
-    _REPLICATION_KWARG = "check_vma"
-except ImportError:  # pragma: no cover - depends on installed JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REPLICATION_KWARG = "check_rep"
 from jax.sharding import PartitionSpec as P
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map with replication checking disabled."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_REPLICATION_KWARG: False})
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def ef_compress(g, err):

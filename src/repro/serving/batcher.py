@@ -59,6 +59,7 @@ semantics"):
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -76,6 +77,8 @@ from repro.serving.resilience import (
     RetryPolicy,
     TransientFailure,
 )
+
+log = logging.getLogger("repro.serving")
 
 
 def bucket(n: int, max_batch: int) -> int:
@@ -280,8 +283,10 @@ class MicroBatcher:
             return False
         try:
             engine = self._degrade()
-        except Exception:
-            return False  # degrade itself failed: stay open, shed fast
+        except Exception:  # degrade itself failed: stay open, shed fast
+            log.warning("%s: degrade to the xla fallback engine failed; "
+                        "the breaker stays open", self.name, exc_info=True)
+            return False
         self.engine = engine
         with self._stats_lock:
             self.degraded += 1

@@ -155,6 +155,33 @@ def test_persistent_fault_trips_breaker_then_sheds_circuit_open(engine):
     assert fi.count("dispatch") == 3  # open breaker never reaches dispatch
 
 
+def test_failed_degrade_is_logged_and_sheds(engine, caplog):
+    """A degrade hook that itself raises leaves the breaker open (typed
+    shedding), and the hook's failure is logged, not swallowed."""
+    import logging
+
+    def broken_degrade():
+        raise RuntimeError("fallback build failed")
+
+    fi = FaultInjector().fail_from("dispatch", 0, error=RuntimeError,
+                                   message="sick tuned kernel")
+    with caplog.at_level(logging.WARNING, logger="repro.serving"):
+        with MicroBatcher(engine, max_batch=1, window_ms=1.0, faults=fi,
+                          retry=RetryPolicy(max_retries=0),
+                          breaker=CircuitBreaker(threshold=1,
+                                                 reset_s=3600.0),
+                          degrade=broken_degrade) as b:
+            first = b.submit(_img(0))
+            with pytest.raises(RuntimeError, match="sick tuned kernel"):
+                first.result(60.0)
+            with pytest.raises(CircuitOpen):
+                b.submit(_img(1)).result(60.0)
+    assert b.stats()["degraded"] == 0
+    failed = [r for r in caplog.records if "degrade" in r.getMessage()]
+    assert failed and failed[0].levelno == logging.WARNING
+    assert "fallback build failed" in caplog.text  # the traceback rides along
+
+
 def test_breaker_half_open_probe_cycle():
     t = [0.0]
     br = CircuitBreaker(threshold=2, reset_s=10.0, clock=lambda: t[0])

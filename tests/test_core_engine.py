@@ -114,3 +114,33 @@ def test_audio_stem_frontend():
     mel = jax.random.normal(KEY, (1, 32, 16))
     y = frontends.audio_stem(p, cfg, mel)
     assert y.shape == (1, 16, cfg.d_model)  # stride-2 downsample
+
+
+def test_init_params_same_weights_in_every_process():
+    """A seed names the same weights in every process: the per-leaf keys
+    must not depend on Python's per-process string-hash salt."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    import repro
+
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.configs import get, tiny_variant\n"
+            "from repro.models.registry import cnn_module\n"
+            "from repro.models.spec import init_params\n"
+            "cfg = tiny_variant(get('resnet18'))\n"
+            "p = init_params(cnn_module(cfg).model_specs(cfg), 0, 'float32')\n"
+            "print(repr([float(jnp.sum(jnp.abs(x)))"
+            " for x in jax.tree.leaves(p)]))\n")
+    src = str(Path(repro.__file__).resolve().parents[1])
+    outs = set()
+    for salt in ("1", "2"):
+        env = {**os.environ, "PYTHONHASHSEED": salt, "JAX_PLATFORMS": "cpu",
+               "PYTHONPATH": src}
+        run = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        outs.add(run.stdout)
+    assert len(outs) == 1, outs

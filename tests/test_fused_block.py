@@ -111,6 +111,32 @@ def test_fused_inverted_residual_bitwise_vs_per_layer_pallas(stride, t,
     np.testing.assert_array_equal(np.asarray(yf), np.asarray(y))
 
 
+# MobileNetV2's stride-2 blocks cut to size: (H, Cin, mid, Cout, block_m).
+# The kernel expands the input's stride phases and reads every depthwise
+# tap as a contiguous window — even and odd sizes, one slab and several,
+# expanded widths (144, 96) Mosaic refused to read with a stride
+_IR_STRIDE2 = [(16, 24, 144, 32, 144), (15, 24, 144, 32, 48),
+               (9, 16, 96, 24, 96)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("h,cin,mid,cout,block_m", _IR_STRIDE2, ids=str)
+def test_fused_inverted_residual_stride2_phases_vs_reference(
+        h, cin, mid, cout, block_m, dtype):
+    dt = jnp.dtype(dtype)
+    x = jax.random.normal(KEY, (1, h, h, cin), dt)
+    w = _ir_weights(cin, mid, cout, dtype)
+    gt = ref.fused_inverted_residual(
+        x.astype(jnp.float32),
+        {k: v.astype(jnp.float32) for k, v in w.items()}, stride=2)
+    y = ops.fused_inverted_residual(x, w, impl="pallas", stride=2,
+                                    block_m=block_m)
+    assert y.shape == gt.shape == (1, -(-h // 2), -(-h // 2), cout)
+    rel = float(jnp.abs(y.astype(jnp.float32) - gt).max()
+                / jnp.abs(gt).max())
+    assert rel < tolerance(dtype), (h, mid, block_m, dtype, rel)
+
+
 def test_fused_inverted_residual_multi_slab_matches_single_slab():
     """Slicing the expanded width into slabs (the tuned block_m) only
     reorders the projection's fp32 accumulation; a non-dividing block_m

@@ -11,6 +11,7 @@ from conftest import spy_algorithms as _spy_algorithms
 from repro.configs import get, tiny_variant
 from repro.core import ConvSpec, InferenceEngine, conv2d
 from repro.core.autotune import cost_model_select, measured_select
+from repro.core.dtypes import tolerance
 from repro.kernels import ops, ref
 
 KEY = jax.random.key(0)
@@ -41,6 +42,28 @@ def test_depthwise_kernel_vs_ground_truth(case):
     y = ops.depthwise(xp, wgt, impl="pallas", stride=stride)
     np.testing.assert_allclose(np.asarray(y), np.asarray(gt), rtol=1e-4,
                                atol=1e-4 * float(jnp.abs(gt).max()))
+
+
+# MobileNetV2's stride-2 depthwise sites cut to size: the kernel reads its
+# taps from stride phases (``phases.split``) — even and odd sizes, slabs
+# of 128 lanes with a ragged last one, widths Mosaic refused to read with
+# a stride (144, 200: wider than 128 lanes)
+DW_STRIDE2_CASES = [(16, 16, 144), (15, 13, 96), (9, 9, 200)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", DW_STRIDE2_CASES, ids=str)
+def test_depthwise_stride2_phases_vs_reference(case, dtype):
+    h, w, c = case
+    x, wgt = _dw_inputs(h, w, c, jnp.dtype(dtype))
+    gt = ref.conv2d_reference(x.astype(jnp.float32),
+                              wgt.astype(jnp.float32), stride=2, groups=c)
+    xp = ref.pad_same(x, 3, 3, stride=2)
+    y = ops.depthwise(xp, wgt, impl="pallas", stride=2, block_c=128)
+    assert y.shape == gt.shape and y.dtype == jnp.dtype(dtype)
+    rel = float(jnp.abs(y.astype(jnp.float32) - gt).max()
+                / jnp.abs(gt).max())
+    assert rel < tolerance(dtype), (case, dtype, rel)
 
 
 @pytest.mark.parametrize("block_c", [8, 32, 128, 512])

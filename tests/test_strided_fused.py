@@ -11,7 +11,8 @@ import pytest
 from repro.configs import get, tiny_variant
 from repro.core import ConvSpec, InferenceEngine, TuningPlan, conv2d
 from repro.core.autotune import Choice, cost_model_select, tunable
-from repro.kernels import ops, ref
+from repro.core.dtypes import tolerance
+from repro.kernels import ops, phases, ref
 
 KEY = jax.random.key(0)
 
@@ -20,6 +21,41 @@ def _mk(b, h, w, c, k, r=3, s=3):
     x = jax.random.normal(KEY, (b, h, w, c))
     wgt = jax.random.normal(jax.random.fold_in(KEY, 7), (r, s, c, k))
     return x, wgt
+
+
+# ---------------------------------------------------------------------
+# thin strided stems: folded into channels (space-to-depth)
+
+# (H, R): the ResNet 7x7/2 and MobileNetV2 3x3/2 RGB stems at small sizes,
+# even and odd
+STEM_CASES = [(32, 7), (33, 7), (32, 3), (17, 3)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("h,r", STEM_CASES, ids=str)
+def test_rgb_stem_folds_into_stride1_conv(h, r, dtype):
+    """An RGB stride-2 stem runs as a ceil(R/2)-tap stride-1 conv over
+    12 channels: the fold is exact, and the kernel with its epilogue holds
+    the dtype's tolerance against the fp32 reference."""
+    x, wgt = _mk(1, h, h, 3, 16, r=r, s=r)
+    xp = ref.pad_same(x, r, r, stride=2)
+    assert phases.foldable(3, 2) and not phases.foldable(64, 2)
+    xq, wq = phases.fold(xp, wgt, 2)
+    assert wq.shape == (-(-r // 2), -(-r // 2), 12, 16)
+    np.testing.assert_allclose(
+        np.asarray(ref.ilpm_conv(xq, wq)),
+        np.asarray(ref.ilpm_conv(xp, wgt, stride=2)), rtol=1e-5, atol=1e-4)
+    scale = jax.random.uniform(jax.random.fold_in(KEY, 3), (16,)) + 0.5
+    bias = jax.random.normal(jax.random.fold_in(KEY, 4), (16,))
+    gt = ref.apply_epilogue(ref.conv2d_reference(x, wgt, stride=2),
+                            scale=scale, bias=bias, act="relu")
+    dt = jnp.dtype(dtype)
+    y = ops.ilpm(xp.astype(dt), wgt.astype(dt), impl="pallas", stride=2,
+                 block_k=64, scale=scale, bias=bias, act="relu")
+    assert y.shape == gt.shape and y.dtype == dt
+    rel = float(jnp.abs(y.astype(jnp.float32) - gt).max()
+                / jnp.abs(gt).max())
+    assert rel < tolerance(dtype), (h, r, dtype, rel)
 
 
 # ---------------------------------------------------------------------
