@@ -91,17 +91,24 @@ class InferenceEngine:
             self._model.forward, cfg=cfg, algorithm=algorithm,
             plan={**plan.choices, **plan.block_choices}
             if plan is not None else None)
-        self._fwd = jax.jit(fwd1)
+
+        # named, so that a profiler trace names the device programs
+        def forward(params, images, winograd_u=None):
+            return fwd1(params, images=images, winograd_u=winograd_u)
+
         # Batch-dim-tolerant entry for the serving layer: map the *exact*
         # single-image computation over the batch inside one jitted call
         # (lax.map), so a micro-batched dispatch is bitwise-equal to N
         # sequential `run` calls — batching changes scheduling, never
         # numerics. One retrace per distinct B; serving pads batches to
         # power-of-two buckets to bound the trace count.
-        self._fwd_batch = jax.jit(
-            lambda params, images, winograd_u=None: jax.lax.map(
+        def forward_batch(params, images, winograd_u=None):
+            return jax.lax.map(
                 lambda im: fwd1(params, images=im[None],
-                                winograd_u=winograd_u)[0], images))
+                                winograd_u=winograd_u)[0], images)
+
+        self._fwd = jax.jit(forward)
+        self._fwd_batch = jax.jit(forward_batch)
         # Streaming entry: the same single-image computation as `run`,
         # jitted with the frame buffer DONATED. A StreamSession
         # device_puts frame t+1 into a fresh slot while frame t computes
@@ -112,7 +119,7 @@ class InferenceEngine:
         # benign, so it's filtered rather than spamming every stream.
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        self._fwd_stream = jax.jit(fwd1, donate_argnames=("images",))
+        self._fwd_stream = jax.jit(forward, donate_argnames=("images",))
 
     # ------------------------------------------------------------------
     # plan construction
@@ -263,6 +270,12 @@ class InferenceEngine:
         """
         return self._fwd_stream(self.params, images=frames,
                                 winograd_u=self.winograd_u or None)[0]
+
+    def compiled_count(self):
+        """Executables the batch-1 and batch forwards hold: one per entry
+        and input shape called so far (a new one is a compile or a
+        persistent-cache load)."""
+        return self._fwd._cache_size() + self._fwd_batch._cache_size()
 
     def trace_count(self):
         """Number of distinct shapes the batch forward has been traced

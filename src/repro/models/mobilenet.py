@@ -139,28 +139,28 @@ def forward(params, cfg, images, *, algorithm="auto", plan=None,
     images = images.astype(cfg.dtype)  # compute precision is cfg.dtype
     plan = plan or {}
     wu = winograd_u or {}
-    x = _conv(params["stem"], images, 2, algorithm,
-              choice=plan.get("stem"), act="relu6", u=wu.get("stem"))
+    x = _conv(params["stem"], images, 2, algorithm, "stem", plan, wu,
+              act="relu6")
     for name, cin, mid, cout, stride in _blocks(cfg):
         p = params[name]
         residual = stride == 1 and cin == cout
         bch = plan.get(f"{name}.block")
         if bch is not None:  # tuner fused this site: one dispatch, not 3
-            x = algorithms.block_inverted_residual(
-                x, p, bch, stride=stride, residual=residual)
+            with jax.named_scope(f"{name}.block"):
+                x = algorithms.block_inverted_residual(
+                    x, p, bch, stride=stride, residual=residual)
             continue
         h = x
         if "pw1" in p:
-            h = _conv(p["pw1"], h, 1, algorithm,
-                      choice=plan.get(f"{name}.pw1"), act="relu6")
-        h = _conv(p["dw"], h, stride, algorithm,
-                  choice=plan.get(f"{name}.dw"), act="relu6")
-        h = _conv(p["pw2"], h, 1, algorithm, choice=plan.get(f"{name}.pw2"))
+            h = _conv(p["pw1"], h, 1, algorithm, f"{name}.pw1", plan,
+                      act="relu6")
+        h = _conv(p["dw"], h, stride, algorithm, f"{name}.dw", plan,
+                  act="relu6")
+        h = _conv(p["pw2"], h, 1, algorithm, f"{name}.pw2", plan)
         if residual:
             h = h + x
         x = h
-    x = _conv(params["head"], x, 1, algorithm, choice=plan.get("head"),
-              act="relu6")
+    x = _conv(params["head"], x, 1, algorithm, "head", plan, act="relu6")
     x = x.mean(axis=(1, 2))
     logits = x @ params["fc"]["w"] + params["fc"]["b"]
     return logits[0] if single else logits

@@ -144,49 +144,46 @@ def block_specs(cfg):
     return specs
 
 
-def _conv(p, x, stride, algorithm, padding="SAME", choice=None, act=None,
-          u=None):
-    """One conv site: folded-BN scale/bias and the activation ride into
-    the kernel as a fused epilogue (``algorithms.conv2d`` threads them to
-    the dispatched kernel's output write)."""
+def _conv(p, x, stride, algorithm, site, plan, wu=None, act=None):
+    """One conv site, under a named scope of its plan name ``site``:
+    folded-BN scale/bias and the activation ride into the kernel as a
+    fused epilogue (``algorithms.conv2d`` threads them to the dispatched
+    kernel's output write)."""
     from repro.core import algorithms
 
-    return algorithms.conv2d(x, p["w"], stride=stride, padding=padding,
-                             algorithm=algorithm, choice=choice,
-                             scale=p["scale"], bias=p["bias"], act=act, u=u)
+    with jax.named_scope(site):
+        return algorithms.conv2d(x, p["w"], stride=stride, padding="SAME",
+                                 algorithm=algorithm, choice=plan.get(site),
+                                 scale=p["scale"], bias=p["bias"], act=act,
+                                 u=(wu or {}).get(site))
 
 
 def _block(p, x, bottleneck, stride, algorithm, name="", plan=None, wu=None):
     """A ``<name>.block`` plan entry replaces the block's final conv AND
     the shortcut add + outer ReLU with one fused dispatch (see
-    ``algorithms.block_residual_conv``); otherwise the tail runs as the
-    per-layer conv followed by a separate XLA add/ReLU pass."""
+    ``algorithms.block_residual_conv``), under a named scope of that
+    entry's name; otherwise the tail runs as the per-layer conv followed
+    by a separate XLA add/ReLU pass."""
     from repro.core import algorithms
 
     plan = plan or {}
-    wu = wu or {}
     idn = x
     if "proj" in p:
-        idn = _conv(p["proj"], x, stride, algorithm,
-                    choice=plan.get(f"{name}.proj"))
+        idn = _conv(p["proj"], x, stride, algorithm, f"{name}.proj", plan)
     bch = plan.get(f"{name}.block")
     if bottleneck:
-        h = _conv(p["c1"], x, 1, algorithm, choice=plan.get(f"{name}.c1"),
+        h = _conv(p["c1"], x, 1, algorithm, f"{name}.c1", plan, act="relu")
+        h = _conv(p["c2"], h, stride, algorithm, f"{name}.c2", plan, wu,
                   act="relu")
-        h = _conv(p["c2"], h, stride, algorithm,
-                  choice=plan.get(f"{name}.c2"), act="relu",
-                  u=wu.get(f"{name}.c2"))
-        if bch is not None:
-            return algorithms.block_residual_conv(h, p["c3"], bch, res=idn)
-        h = _conv(p["c3"], h, 1, algorithm, choice=plan.get(f"{name}.c3"))
+        tail = "c3"
     else:
-        h = _conv(p["c1"], x, stride, algorithm,
-                  choice=plan.get(f"{name}.c1"), act="relu",
-                  u=wu.get(f"{name}.c1"))
-        if bch is not None:
-            return algorithms.block_residual_conv(h, p["c2"], bch, res=idn)
-        h = _conv(p["c2"], h, 1, algorithm, choice=plan.get(f"{name}.c2"),
-                  u=wu.get(f"{name}.c2"))
+        h = _conv(p["c1"], x, stride, algorithm, f"{name}.c1", plan, wu,
+                  act="relu")
+        tail = "c2"
+    if bch is not None:
+        with jax.named_scope(f"{name}.block"):
+            return algorithms.block_residual_conv(h, p[tail], bch, res=idn)
+    h = _conv(p[tail], h, 1, algorithm, f"{name}.{tail}", plan, wu)
     return jax.nn.relu(h + idn)
 
 
@@ -219,8 +216,8 @@ def forward(params, cfg, images, *, algorithm="ilpm", plan=None,
     wu = winograd_u or {}
     blocks = cfg.extra["blocks"]
     bottleneck = cfg.extra["bottleneck"]
-    x = _conv(params["stem"], images, 2, algorithm,
-              choice=plan.get("stem"), act="relu", u=wu.get("stem"))
+    x = _conv(params["stem"], images, 2, algorithm, "stem", plan, wu,
+              act="relu")
     x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
                               (1, 2, 2, 1), "SAME")
     for si, n in enumerate(blocks):

@@ -25,8 +25,11 @@ The typed-exception hierarchy (``Rejected`` > ``Overloaded`` /
 ``DeadlineExceeded`` / ``CircuitOpen``, plus the wire-tier
 ``ProtocolError`` / ``BadRequest`` / ``RemoteError``) is exported here —
 clients never import from ``resilience``/``request`` internals. See
-docs/serving.md ("Front door", "Overload & failure semantics").
+docs/serving.md ("Front door", "Overload & failure semantics"). ``spans``
+records spans on the served request path (docs/serving.md,
+"Observability").
 """
+from repro.serving import spans  # noqa: F401
 from repro.serving.batcher import MicroBatcher, bucket  # noqa: F401
 from repro.serving.client import AsyncClient  # noqa: F401
 from repro.serving.engine_cache import (  # noqa: F401
@@ -114,6 +117,7 @@ __all__ = [
     "pack_frame",
     "plan_key",
     "read_frame",
+    "spans",
     "unpack_body",
     "xla_fallback_plan",
 ]

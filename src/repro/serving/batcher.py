@@ -66,8 +66,10 @@ from collections import deque
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.serving import request as req_mod
+from repro.serving import spans
 from repro.serving.request import Request, Ticket
 from repro.serving.resilience import (
     CircuitBreaker,
@@ -79,6 +81,8 @@ from repro.serving.resilience import (
 )
 
 log = logging.getLogger("repro.serving")
+
+DISPATCH_LOG = 1024  # recent dispatches that stats()' latencies are over
 
 
 def bucket(n: int, max_batch: int) -> int:
@@ -129,7 +133,11 @@ class MicroBatcher:
         self._faults = faults        # FaultInjector, or None
         self._scheduler = scheduler  # DeviceScheduler, or None (inline)
         self.pad_batches = pad_batches
-        self.dispatches: list[dict] = []  # {batch, padded, latencies}
+        # the recent dispatches, {batch, padded, latencies}; the request
+        # count and batch histogram over the batcher's life run beside it
+        self.dispatches: deque[dict] = deque(maxlen=DISPATCH_LOG)
+        self._requests = 0
+        self._histogram: dict[int, int] = {}
         # the dispatch path appends to the dispatch log while stats()
         # reads it from caller threads: every access takes this lock
         self._stats_lock = threading.Lock()
@@ -232,6 +240,9 @@ class MicroBatcher:
                     self._cond.wait()
                 if not self._pending:  # closed and drained: exit
                     return
+                rec = spans.active
+                if rec is not None:
+                    t_window = time.perf_counter_ns()
                 # the batching window is anchored at the OLDEST pending
                 # request's arrival — a batch that formed while the
                 # previous dispatch held the device goes out immediately
@@ -245,6 +256,8 @@ class MicroBatcher:
                 take = min(len(self._pending), self.max_batch)
                 raw = [self._pending.popleft() for _ in range(take)]
                 drain = self._closed
+            if rec is not None:
+                t_take = time.perf_counter_ns()
             batch = [r for r in raw if self._take(r)]
             if not batch:
                 continue  # everything shed at dequeue: no dispatch
@@ -253,27 +266,59 @@ class MicroBatcher:
                      else "window")
             with self._stats_lock:
                 self._causes[cause] += 1
-            self._dispatch(batch)
+            span = None
+            if rec is not None:
+                span = spans.Dispatch(rec, spans.new_id(),
+                                      tuple(r.id for r in batch))
+                span.add("batcher.window", t_window, t_take)
+                for r in batch:
+                    rec.add("batcher.wait", spans.ns(r.arrival), t_take,
+                            r.id)
+            self._dispatch(batch, span)
 
     # ------------------------------------------------------------------
     # dispatch with retry / breaker / degraded-mode fallback
 
-    def _run(self, batch: list[Request]):
-        if len(batch) == 1:
-            # the paper's single-image fast path: tuned per-layer
-            # dispatch on exactly one image, no stacking, no padding
-            outs = [self.engine.run(batch[0].image)]
+    def _run(self, batch: list[Request], span=None):
+        if span is not None:
+            compiled = _compiled(self.engine)
+            t0 = time.perf_counter_ns()
+        n = len(batch)
+        if n == 1:
             padded = 1
         else:
-            n = len(batch)
             padded = bucket(n, self.max_batch) if self.pad_batches else n
             images = [r.image for r in batch]
             images += [images[-1]] * (padded - n)  # filler rows
-            logits = self.engine.run_batch(jnp.stack(images))
-            outs = [logits[i] for i in range(n)]
+            images = jnp.stack(images)
+        if span is not None:
+            t1 = time.perf_counter_ns()
+        if n == 1:
+            # the paper's single-image fast path: tuned per-layer
+            # dispatch on exactly one image, no stacking, no padding
+            # (the call itself sends the image and slices out its row)
+            logits = self.engine.run(batch[0].image)
+        else:
+            logits = self.engine.run_batch(images)
+        if span is not None:
+            t2 = time.perf_counter_ns()
+        outs = [logits] if n == 1 else [logits[i] for i in range(n)]
+        if span is not None:
+            t3 = time.perf_counter_ns()
         # settle async dispatch before resolving: futures hand back
         # finished results, and latency stamps include the compute
-        return jax.block_until_ready(outs), padded
+        outs = jax.block_until_ready(outs)
+        if span is not None:
+            t4 = time.perf_counter_ns()
+            span.add("engine.inputs", t0, t1)
+            span.add("engine.call", t1, t2, {
+                "batch": n, "padded": padded,
+                "h2d_bytes": _host_bytes(batch, padded)})
+            span.add("engine.outputs", t2, t3)
+            span.add("engine.ready", t3, t4)
+            if compiled is not None and _compiled(self.engine) != compiled:
+                span.add("engine.first_call", t1, t4, {"padded": padded})
+        return outs, padded
 
     def _try_degrade(self) -> bool:
         """Swap in the degraded (xla-only) engine via the owner's hook.
@@ -293,7 +338,7 @@ class MicroBatcher:
         self.breaker.reset()
         return True
 
-    def _attempt(self, batch: list[Request]):
+    def _attempt(self, batch: list[Request], span=None):
         """Run ``batch`` to completion under the resilience policy:
         transient failures retry with backoff, every failure feeds the
         breaker, a trip attempts the degraded-mode engine swap, and an
@@ -316,7 +361,7 @@ class MicroBatcher:
                     delay = self._faults.check("dispatch")
                     if delay:
                         time.sleep(delay)
-                outs, padded = self._run(batch)
+                outs, padded = self._run(batch, span)
             except Exception as e:
                 tripped = self.breaker.record_failure()
                 if tripped and self._try_degrade():
@@ -333,65 +378,75 @@ class MicroBatcher:
             self.breaker.record_success()
             return outs, padded
 
-    def _dispatch(self, batch: list[Request]) -> None:
+    def _dispatch(self, batch: list[Request], span=None) -> None:
         try:
             if self._scheduler is not None:
                 # the shared device thread runs the attempt under the
                 # cross-network fairness policy; this loop thread blocks
                 # here while the NEXT batch keeps forming via submit()
                 outs, padded = self._scheduler.run(
-                    lambda: self._attempt(batch),
+                    lambda: self._attempt(batch, span),
                     urgency=min(r.urgency for r in batch),
                     priority=max(r.priority for r in batch),
-                    network=self.name)
+                    network=self.name, span=span)
             else:
-                outs, padded = self._attempt(batch)
+                outs, padded = self._attempt(batch, span)
         except Exception as e:  # resolve, don't kill the loop
             for r in batch:
                 req_mod.fail(r, e)
             return
+        if span is not None:
+            t0 = time.perf_counter_ns()
         for r, o in zip(batch, outs):
             req_mod.resolve(r, o)
+        if span is not None:
+            span.add("batcher.resolve", t0, time.perf_counter_ns())
+            for r in batch:
+                span.rec.add("serve.request", spans.ns(r.arrival),
+                             spans.ns(r.done), r.id)
         with self._stats_lock:
             self.dispatches.append({
                 "batch": len(batch),
                 "padded": padded,
                 "latencies": [r.latency for r in batch],
             })
+            self._requests += len(batch)
+            self._histogram[len(batch)] = \
+                self._histogram.get(len(batch), 0) + 1
 
     # ------------------------------------------------------------------
 
     def stats(self) -> dict:
-        """Dispatch-log aggregates: request count, batch-size histogram,
-        latency mean/p50/p95/max (seconds, submit -> future resolution),
-        live queue depth, mid-flight joins, dispatch causes (full batch
-        vs expired window vs shutdown drain), deadline misses if an SLO
-        is set, and the resilience counters (sheds by cause, retries,
-        breaker state, degraded-mode swaps)."""
+        """Request count and batch-size histogram over the batcher's
+        life; latency mean/p50/p95/max (seconds, submit -> future
+        resolution) and deadline misses over the last ``DISPATCH_LOG``
+        dispatches; live queue depth, mid-flight joins, dispatch causes
+        (full batch vs expired window vs shutdown drain), and the
+        resilience counters (sheds by cause, retries, breaker state,
+        degraded-mode swaps)."""
         with self._cond:
             depth = len(self._pending)
             joined = self._joined
         with self._stats_lock:  # snapshot: the dispatch path appends live
-            dispatches = list(self.dispatches)
+            recent = list(self.dispatches)
+            requests = self._requests
+            hist = dict(self._histogram)
             causes = dict(self._causes)
             shed = dict(self._shed)
             retries = self._retries
             degraded = self.degraded
-        lats = sorted(l for d in dispatches for l in d["latencies"])
+        lats = sorted(l for d in recent for l in d["latencies"])
 
         def pct(q):
             if not lats:
                 return None
             return lats[min(len(lats) - 1, round(q / 100 * (len(lats) - 1)))]
 
-        hist: dict[int, int] = {}
-        for d in dispatches:
-            hist[d["batch"]] = hist.get(d["batch"], 0) + 1
         misses = (None if self.deadline_s is None
                   else sum(1 for l in lats if l > self.deadline_s))
         return {
-            "requests": len(lats),
-            "dispatches": len(dispatches),
+            "requests": requests,
+            "dispatches": sum(hist.values()),
             "queue_depth": depth,
             "max_queue": self.max_queue,
             "window_ms": self.window_s * 1e3,
@@ -413,3 +468,18 @@ class MicroBatcher:
             "latency_p95_s": pct(95),
             "latency_max_s": max(lats) if lats else None,
         }
+
+
+def _host_bytes(batch, padded: int) -> int:
+    """Image bytes a dispatch sends from the host, filler rows included
+    (an image already on the device sends none)."""
+    sizes = [r.image.nbytes if isinstance(r.image, np.ndarray) else 0
+             for r in batch]
+    return sum(sizes) + sizes[-1] * (padded - len(batch))
+
+
+def _compiled(engine):
+    """How many compiled executables the engine's forward entries hold, or
+    None for an engine that does not say (a test's stub)."""
+    count = getattr(engine, "compiled_count", None)
+    return None if count is None else count()

@@ -40,6 +40,7 @@ from collections import OrderedDict
 import jax
 
 from repro.core.engine import InferenceEngine
+from repro.serving import spans
 from repro.serving.resilience import RetryPolicy, TransientFailure
 
 log = logging.getLogger("repro.serving")
@@ -167,8 +168,14 @@ class EngineCache:
                     return eng
                 pkey = plan_key(cfg)
                 plan = self._plans.get(pkey)
+            rec = spans.active
+            if rec is not None:
+                t0 = time.perf_counter_ns()
             eng, degraded = self._build(cfg, params=params, seed=seed,
                                         plan=plan)
+            if rec is not None:
+                rec.add("engine.build", t0, time.perf_counter_ns(),
+                        spans.new_id())
             with self._lock:
                 self.misses += 1
                 if degraded:

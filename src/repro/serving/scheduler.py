@@ -27,20 +27,24 @@ from __future__ import annotations
 import heapq
 import itertools
 import threading
+import time
 
 
 class _Job:
     """One queued dispatch: the thunk, its ordering key, and a settled
-    flag the submitting batcher blocks on."""
+    flag the submitting batcher blocks on; with ``span`` (a
+    ``spans.Dispatch``), the stamps of its enqueue and of its end."""
 
-    __slots__ = ("fn", "network", "done", "result", "error")
+    __slots__ = ("fn", "network", "done", "result", "error", "span",
+                 "t_enqueued", "t_done")
 
-    def __init__(self, fn, network):
+    def __init__(self, fn, network, span):
         self.fn = fn
         self.network = network
         self.done = threading.Event()
         self.result = None
         self.error: BaseException | None = None
+        self.span = span
 
 
 class DeviceScheduler:
@@ -67,15 +71,20 @@ class DeviceScheduler:
     # ------------------------------------------------------------------
 
     def run(self, fn, *, urgency: float, priority: int = 0,
-            network: str | None = None):
+            network: str | None = None, span=None):
         """Execute ``fn`` on the device thread; blocks until done.
 
         ``urgency`` is the time key (absolute ``perf_counter`` value —
         a deadline or an arrival; smaller dispatches first). ``priority``
         sorts above it: a higher-priority job beats any lower-priority
-        one regardless of age.
+        one regardless of age. ``span`` (a ``spans.Dispatch``, while
+        recording) gets the job's ``scheduler.queue`` (enqueued -> the
+        device thread starts it) and ``scheduler.return`` (done -> this
+        caller resumes) spans.
         """
-        job = _Job(fn, network or "?")
+        job = _Job(fn, network or "?", span)
+        if span is not None:
+            job.t_enqueued = time.perf_counter_ns()
         with self._cond:
             if self._closed:
                 raise RuntimeError(
@@ -86,6 +95,8 @@ class DeviceScheduler:
                                          len(self._heap))
             self._cond.notify()
         job.done.wait()
+        if span is not None:
+            span.add("scheduler.return", job.t_done, time.perf_counter_ns())
         if job.error is not None:
             raise job.error
         return job.result
@@ -98,10 +109,15 @@ class DeviceScheduler:
                 if not self._heap and self._closed:
                     return
                 _key, _seq, job = heapq.heappop(self._heap)
+            if job.span is not None:
+                job.span.add("scheduler.queue", job.t_enqueued,
+                             time.perf_counter_ns())
             try:
                 job.result = job.fn()
             except BaseException as e:  # noqa: BLE001 - relayed, not eaten
                 job.error = e
+            if job.span is not None:
+                job.t_done = time.perf_counter_ns()
             with self._cond:
                 self._completed[job.network] = \
                     self._completed.get(job.network, 0) + 1

@@ -156,6 +156,37 @@ def test_batcher_padding_bounds_traces():
     assert eng.trace_count() <= 2  # one per bucket, not one per batch size
 
 
+def test_batcher_dispatch_log_stays_bounded_and_counts_stay_exact(
+        monkeypatch):
+    """The dispatch log keeps the recent dispatches only; the request
+    count and batch histogram count every dispatch of the batcher's life."""
+    from repro.serving import batcher as batcher_mod
+
+    class Echo:
+        def run(self, image):
+            return np.asarray(image)[0, 0]
+
+        def run_batch(self, images):
+            return np.asarray(images)[:, 0, 0]
+
+    monkeypatch.setattr(batcher_mod, "DISPATCH_LOG", 8)
+    n = 30
+    with MicroBatcher(Echo(), max_batch=2, window_ms=0.0) as b:
+        for i in range(n):
+            b.submit(np.full((2, 2, 3), i, np.float32)).result(timeout=60)
+        pair = [b.submit(np.zeros((2, 2, 3), np.float32)) for _ in range(2)]
+        for t in pair:
+            t.result(timeout=60)
+    st = b.stats()
+    assert len(b.dispatches) == 8
+    assert st["requests"] == n + 2
+    assert sum(k * v for k, v in st["batch_histogram"].items()) == n + 2
+    assert st["dispatches"] == sum(st["batch_histogram"].values())
+    assert st["dispatches"] == sum(st["dispatch_causes"].values())
+    recent = sum(len(d["latencies"]) for d in b.dispatches)
+    assert st["latency_max_s"] is not None and recent <= 16
+
+
 def test_batcher_dispatch_error_resolves_futures():
     """A failing dispatch must surface on the futures, not kill the loop."""
     eng = InferenceEngine(RESNET)
