@@ -13,14 +13,18 @@ goes to the device when it fills, or when the window measured from its
 a long dispatch goes out the moment the engine frees up, never paying a
 fresh window on top of the wait (the continuous-batching property; the
 deadline-window design it replaces restarted the window at dequeue).
-Dispatch shape:
+Dispatch shape — one device program a dispatch, the engine's jitted
+entry; no eager JAX operation runs before or after it:
 
   * **batch == 1** — the single-image fast path: ``engine.run(image)``,
     exactly the paper's tuned per-layer dispatch, zero batching overhead;
-  * **batch > 1**  — one ``engine.run_batch`` call on the stacked images,
-    padded up to a power-of-two bucket (re-using the last image as filler)
-    so a ragged final micro-batch doesn't cost a fresh jit trace for every
-    distinct batch size.
+    it returns the request's (classes,) row;
+  * **batch > 1**  — one ``engine.run_batch`` call on the batch's images
+    as a list, padded up to a power-of-two bucket (re-using the last image
+    as filler) so a ragged final micro-batch doesn't cost a fresh jit
+    trace for every distinct batch size. The engine stacks them inside
+    its program and returns a tuple of rows; each request is handed its
+    row as returned, and the filler rows are dropped.
 
 ``run_batch`` maps the *single-image* computation over the batch inside
 one jitted call (``lax.map``), so outputs are bitwise-equal to sequential
@@ -65,7 +69,6 @@ import time
 from collections import deque
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.serving import request as req_mod
@@ -284,25 +287,22 @@ class MicroBatcher:
             compiled = _compiled(self.engine)
             t0 = time.perf_counter_ns()
         n = len(batch)
-        if n == 1:
-            padded = 1
-        else:
-            padded = bucket(n, self.max_batch) if self.pad_batches else n
-            images = [r.image for r in batch]
-            images += [images[-1]] * (padded - n)  # filler rows
-            images = jnp.stack(images)
+        images = [r.image for r in batch]
+        padded = bucket(n, self.max_batch) if self.pad_batches else n
+        images += [images[-1]] * (padded - n)  # filler rows
         if span is not None:
             t1 = time.perf_counter_ns()
+        # one device program a dispatch, the engine's jitted entry: it
+        # takes the images as they are and hands back one row a request
         if n == 1:
             # the paper's single-image fast path: tuned per-layer
             # dispatch on exactly one image, no stacking, no padding
-            # (the call itself sends the image and slices out its row)
-            logits = self.engine.run(batch[0].image)
+            rows = [self.engine.run(images[0])]
         else:
-            logits = self.engine.run_batch(images)
+            rows = self.engine.run_batch(images)
         if span is not None:
             t2 = time.perf_counter_ns()
-        outs = [logits] if n == 1 else [logits[i] for i in range(n)]
+        outs = list(rows)[:n]  # filler rows dropped
         if span is not None:
             t3 = time.perf_counter_ns()
         # settle async dispatch before resolving: futures hand back

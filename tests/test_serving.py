@@ -142,6 +142,100 @@ def test_batcher_single_request_takes_fast_path(monkeypatch):
     assert out.shape == (RESNET.vocab_size,)
 
 
+def _host(images):
+    return [np.asarray(im) for im in images]
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("where", ["host", "device"])
+def test_warm_dispatch_launches_only_the_engines_jitted_entry(
+        n, where, monkeypatch):
+    """A warm dispatch, at batch 1 and at batch 4, from host or device
+    images, binds no JAX operation outside the engine's jitted entry: no
+    stack before it, no row slices after it."""
+    from jax._src import core as jax_core
+
+    eng = InferenceEngine(RESNET)
+    imgs = _images(n) if where == "device" else _host(_images(n))
+    with MicroBatcher(eng, max_batch=4, window_ms=250.0) as b:
+        def dispatch():
+            tickets = [b.submit(im) for im in imgs]
+            return [t.result(timeout=600) for t in tickets]
+        dispatch()  # compiles the entry for this bucket
+        eager = []
+        real = jax_core.EvalTrace.process_primitive
+
+        def counting(self, primitive, tracers, params):
+            eager.append(primitive.name)
+            return real(self, primitive, tracers, params)
+        monkeypatch.setattr(jax_core.EvalTrace, "process_primitive",
+                            counting)
+        outs = dispatch()
+        monkeypatch.undo()
+    assert eager == []
+    assert [d["batch"] for d in b.dispatches] == [n, n]
+    assert all(o.shape == (RESNET.vocab_size,) for o in outs)
+
+
+@pytest.mark.parametrize("n", [1, 3, 4])
+def test_batcher_hands_each_request_the_row_the_engine_returned(n):
+    """The entry gets the requests' own images, unstacked, filler rows
+    repeating the last; each request gets the very object the entry
+    returned for its row; the filler rows go to nobody."""
+    class RowStub:
+        def __init__(self):
+            self.given, self.returned = [], []
+
+        def run(self, image):
+            self.given.append([image])
+            self.returned.append((image[0, 0].copy(),))
+            return self.returned[-1][0]
+
+        def run_batch(self, images):
+            self.given.append(list(images))
+            self.returned.append(tuple(im[0, 0].copy() for im in images))
+            return self.returned[-1]
+
+    eng = RowStub()
+    sent = [np.full((2, 2, 3), i, np.float32) for i in range(n)]
+    with MicroBatcher(eng, max_batch=4, window_ms=250.0) as b:
+        tickets = [b.submit(im) for im in sent]
+        got = [t.result(timeout=60) for t in tickets]
+    (given,), (rows,) = eng.given, eng.returned
+    padded = bucket(n, 4)
+    assert len(given) == len(rows) == padded
+    assert all(g is s for g, s in zip(given, sent + sent[-1:] * padded))
+    assert all(g is r for g, r in zip(got, rows))
+    assert [float(g[0]) for g in got] == list(range(n))
+
+
+@pytest.mark.parametrize("where", ["host", "device"])
+def test_engine_entries_return_the_programs_rows(where):
+    """``run`` returns what the ``forward`` program outputs, the row
+    itself; ``run_batch`` returns a tuple of the ``forward_batch``
+    program's row outputs, bitwise-equal to the rows of the stacked
+    batch's program, and read by ``jnp`` as that (B, classes) array."""
+    import jax.numpy as jnp
+
+    eng = InferenceEngine(RESNET)
+    u = eng.winograd_u or None
+    host = _host(_images(4))
+    imgs = host if where == "host" else [jax.device_put(im) for im in host]
+    rows = eng.run_batch(imgs)
+    want = eng._fwd_batch(eng.params, np.stack(host), winograd_u=u)
+    assert isinstance(rows, tuple) and len(rows) == len(want) == 4
+    for got, row in zip(rows, want):
+        assert isinstance(got, jax.Array)
+        assert got.shape == (RESNET.vocab_size,)
+        assert np.array_equal(np.asarray(got), np.asarray(row))
+    assert np.array_equal(np.asarray(jnp.roll(rows, 1, axis=0)[1]),
+                          np.asarray(rows[0]))
+    one = eng.run(imgs[0])
+    fwd = eng._fwd(eng.params, images=host[0][None], winograd_u=u)
+    assert one.shape == fwd.shape == (RESNET.vocab_size,)
+    assert np.array_equal(np.asarray(one), np.asarray(fwd))
+
+
 def test_batcher_padding_bounds_traces():
     """Ragged batch sizes pad to power-of-two buckets, so distinct traced
     batch shapes stay O(log max_batch) regardless of traffic pattern."""
