@@ -1,14 +1,14 @@
 """One-chip smoke run of the served single-image path at published widths.
 
-Serves ResNet-18 and MobileNetV2 at 224x224 fp32 through the normal entry
-points (``repro.serving.Server`` with the default ``ServingOptions`` ->
-``MicroBatcher`` -> ``InferenceEngine`` with its cost-model plan -> the
-Pallas kernels) on one TPU, from seeded random weights and images, and
-checks what comes back:
+Serves ResNet-18, MobileNetV2 and EfficientNet-B0 at 224x224 fp32 through
+the normal entry points (``repro.serving.Server`` with the default
+``ServingOptions`` -> ``MicroBatcher`` -> ``InferenceEngine`` with its
+cost-model plan -> the Pallas kernels) on one TPU, from seeded random
+weights and images, and checks what comes back:
 
   * every request resolves (none failed, none shed), and at least one
     micro-batch of two or more images was dispatched;
-  * neither plan has an ``xla`` site, each compiled forward contains a
+  * no plan has an ``xla`` site, each compiled forward contains a
     Pallas kernel (``tpu_custom_call``), and no engine was degraded to the
     XLA fallback plan;
   * every served logit vector matches a plain reference (the same network
@@ -36,7 +36,7 @@ import os
 import sys
 import time
 
-NETWORKS = ("resnet18", "mobilenet_v2")
+NETWORKS = ("resnet18", "mobilenet_v2", "efficientnet_b0")
 SEED = 0
 N_SINGLE = 4        # sequential requests per network
 N_CONCURRENT = 4    # submitted together, so the batcher forms a batch >= 2

@@ -10,6 +10,7 @@ from repro.configs.base import (  # noqa: F401
 )
 from repro.configs import (  # noqa: F401
     deepseek_v2_236b,
+    efficientnet,
     granite_3_2b,
     granite_8b,
     granite_moe_3b,

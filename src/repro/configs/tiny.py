@@ -27,6 +27,12 @@ def tiny_variant(cfg: ArchConfig) -> ArchConfig:
             extra.update(settings=((1, 16, 1, 1), (6, 24, 1, 2),
                                    (6, 24, 1, 1), (6, 40, 1, 2)),
                          stem=16, head=64)
+        if "rows" in extra:  # efficientnet: one block per row, keeping a
+            # t=1 row, 5x5 rows at stride 2 and at stride 1 (the latter
+            # residual), a 3x3 stride-2 row, and SE in every block
+            extra.update(rows=((1, 3, 1, 16, 1), (6, 5, 2, 24, 1),
+                               (6, 5, 1, 24, 1), (6, 3, 2, 40, 1)),
+                         stem=16, head=64)
         return cfg.replace(**{**kw, "extra": extra})
 
     if cfg.attn_impl == "mla":

@@ -19,10 +19,14 @@ im2col/libdnn/winograd are stride-1-only; forcing one of them on a strided
 site falls back to ilpm.
 
 The optional fused epilogue — ``scale``/``bias`` (folded BatchNorm, (K,)
-vectors) and ``act`` ('relu' | 'relu6') — is threaded through dispatch into
-the kernels, which apply it inside their output write: conv+BN+act costs
-one HBM pass instead of three. The XLA escape hatch applies the identical
-math as separate ops. ``u`` optionally carries a precomputed Winograd
+vectors) and ``act`` ('relu' | 'relu6' | 'swish' | 'sigmoid') — is threaded
+through dispatch into the kernels, which apply it inside their output
+write: conv+BN+act costs one HBM pass instead of three. A squeeze-and-
+excitation block (EfficientNet) adds two operands: ``pool`` makes its
+depthwise conv return its per-channel spatial sums as well, written by the
+depthwise kernel's epilogue, and ``gate`` scales its projection's input
+channels inside the pointwise kernel. The XLA escape hatch applies the
+identical math as separate ops. ``u`` optionally carries a precomputed Winograd
 filter transform (see ``InferenceEngine``: computed once per plan build).
 
 Grouped convs are detected from the filter shape: HWIO filters carry
@@ -44,19 +48,54 @@ from repro.kernels import ops, ref
 STRIDED_DENSE = ("ilpm", "direct")
 
 
-def _auto(x, w, stride, epilogue=False):
+def _auto(x, w, stride, epilogue=False, pool=False, gated=False):
     """Trace-time tuner lookup (memoized per ConvSpec). ``epilogue``
     matches the costing to the call: a site dispatching fused BN/act must
     be selected as its fused variant, the same way the engine's plans are
-    built (``build_plan(..., epilogue=True)``)."""
-    spec = ConvSpec.from_tensors(x, w, stride)
+    built (``build_plan(..., epilogue=True)``); so do the squeeze-and-
+    excitation flags."""
+    spec = ConvSpec.from_tensors(x, w, stride, pool=pool, gated=gated)
     tuned = autotune.select(spec, epilogue=epilogue)
     return tuned.algorithm, dict(tuned.params)
 
 
+def _gated_in_kernel(x, w, stride, algorithm, choice, ep_on):
+    """Whether a gated conv's route ends at the pointwise kernel, which
+    applies the gate to its input tile."""
+    if w.shape[:3] != (1, 1, x.shape[-1]):
+        return False
+    if choice is not None:
+        algorithm = choice.algorithm
+    if algorithm == "auto":
+        algorithm, _ = _auto(x, w, stride, epilogue=ep_on, gated=True)
+    return algorithm == "pointwise"
+
+
 def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
-           choice=None, scale=None, bias=None, act=None, u=None):
-    """x: (B,H,W,C) NHWC; w: (R,S,C/groups,K) HWIO -> (B,H',W',K)."""
+           choice=None, scale=None, bias=None, act=None, u=None, pool=False,
+           gate=None):
+    """x: (B,H,W,C) NHWC; w: (R,S,C/groups,K) HWIO -> (B,H',W',K).
+
+    The squeeze-and-excitation operands: with ``pool`` the call returns
+    ``(y, sums)``, ``sums`` the (B, K) fp32 spatial sums of ``y``, which
+    the depthwise kernel writes from its epilogue; ``gate`` (B, C) scales
+    ``x``'s channels before the conv, inside the pointwise kernel. Every
+    other route computes the same in jnp around its conv.
+    """
+    ep_on = scale is not None or bias is not None or act is not None
+    if gate is not None and not _gated_in_kernel(x, w, stride, algorithm,
+                                                 choice, ep_on):
+        x, gate = ref.gate_channels(x, gate), None
+    y = _conv2d(x, w, stride=stride, padding=padding, algorithm=algorithm,
+                impl=impl, choice=choice, scale=scale, bias=bias, act=act,
+                u=u, pool=pool, gate=gate)
+    if pool and not isinstance(y, tuple):  # not the depthwise kernel
+        y = (y, ref.channel_sums(y))
+    return y
+
+
+def _conv2d(x, w, *, stride, padding, algorithm, impl, choice, scale, bias,
+            act, u, pool, gate):
     R, S, Cg, K = w.shape
     C = x.shape[-1]
     assert C % Cg == 0, f"image channels {C} vs filter depth {Cg}"
@@ -75,7 +114,8 @@ def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
     # ---- grouped family: depthwise kernel or XLA fallback ------------
     if groups > 1:
         if algorithm == "auto":
-            algorithm, params = _auto(x, w, stride, epilogue=ep_on)
+            algorithm, params = _auto(x, w, stride, epilogue=ep_on,
+                                      pool=pool)
         depthwise_ok = groups == C and K % C == 0 and stride in (1, 2)
         if algorithm != "depthwise" or not depthwise_ok:
             # tuner punted, or a grouped-but-not-depthwise conv
@@ -83,6 +123,8 @@ def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
                 ref.conv2d_reference(x, w, stride=stride, padding=padding,
                                      groups=groups), **ep)
         xp = ref.pad_same(x, R, S, stride=stride) if padding == "SAME" else x
+        if pool:
+            params["pool"] = True
         return ops.dispatch("depthwise", xp, w, impl=impl, stride=stride,
                             **ep, **params)
 
@@ -98,7 +140,8 @@ def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
         return ref.apply_epilogue(y.reshape(B, hp, wp, K), **ep)
 
     if algorithm == "auto":
-        algorithm, params = _auto(x, w, stride, epilogue=ep_on)
+        algorithm, params = _auto(x, w, stride, epilogue=ep_on,
+                                  gated=gate is not None)
         if algorithm == "xla":  # tuner punted (e.g. stride > 2)
             return ref.apply_epilogue(
                 ref.conv2d_reference(x, w, stride=stride, padding=padding),
@@ -108,6 +151,8 @@ def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
         if (R, S) != (1, 1):
             algorithm = "ilpm"  # pointwise kernel is 1x1-only -> best dense
         else:
+            if gate is not None:
+                params["gate"] = gate
             return ops.dispatch("pointwise", x, w, impl=impl, stride=stride,
                                 **ep, **params)
 
@@ -135,16 +180,17 @@ def conv2d(x, w, *, stride=1, padding="SAME", algorithm="auto", impl="auto",
 # ---- fused blocks: one dispatch where the per-layer path makes 2-3 ----
 
 def block_inverted_residual(x, p, choice, *, stride=1, residual=False,
-                            impl="auto"):
+                            act="relu6", impl="auto"):
     """Run a whole inverted-residual block as one fused dispatch.
 
     ``p`` is the model's param subtree for the block — optional ``pw1``
     plus ``dw``/``pw2``, each a ``{"w", "scale", "bias"}`` conv site —
     flattened here into the stage-keyed weights dict the block kernel
     takes. ``choice`` is the plan's block-site Choice (algorithm +
-    tuned ``block_m``); activations are MobileNetV2's fixed ReLU6 /
-    linear-projection pattern, so they're call-site constants, not plan
-    state.
+    tuned ``block_m``); activations follow the network's pattern (``act``
+    after the expansion and depthwise convs, a linear projection), so
+    they're call-site constants, not plan state. A block with a squeeze-
+    and-excitation gate never gets here: the tuner does not fuse it.
     """
     weights = {"wdw": p["dw"]["w"], "sdw": p["dw"]["scale"],
                "bdw": p["dw"]["bias"],
@@ -154,7 +200,7 @@ def block_inverted_residual(x, p, choice, *, stride=1, residual=False,
         weights.update({"w1": p["pw1"]["w"], "s1": p["pw1"]["scale"],
                         "b1": p["pw1"]["bias"]})
     return ops.dispatch_block(choice.algorithm, x, weights, impl=impl,
-                              stride=stride, residual=residual, act="relu6",
+                              stride=stride, residual=residual, act=act,
                               out_act=None, **dict(choice.params))
 
 

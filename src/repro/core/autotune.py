@@ -77,6 +77,11 @@ def tunable(spec: ConvSpec) -> bool:
       * dense 1x1 convs — the pointwise kernel (on the subsampled image
         at stride 2).
 
+    Any depthwise filter size qualifies (EfficientNet's 5x5 depthwise
+    convs at stride 1 and 2 included), and so do the squeeze-and-
+    excitation variants of the depthwise and 1x1 families (``spec.pool``
+    / ``spec.gated``).
+
     Everything else (grouped non-depthwise convs, strides > 2) runs on the
     XLA reference path; such sites still get a plan entry with an ``xla``
     Choice.
@@ -96,9 +101,14 @@ def xla_choice(spec: ConvSpec, *, peak_flops=PEAK_FLOPS,
 
     With ``epilogue=True`` the site wants a scale/bias/act applied; the
     escape hatch runs it as a separate XLA pass, so it pays an extra
-    read+write of the output that the fused kernels do not.
+    read+write of the output that the fused kernels do not. So with a
+    squeeze-and-excitation variant: a ``gated`` site's gating is a
+    read+write of the input, a ``pool`` site's sums a read of the output.
     """
-    bts = spec.bytes_min + (spec.epilogue_bytes if epilogue else 0)
+    el = _el(spec)
+    bts = spec.bytes_min + (spec.epilogue_bytes if epilogue else 0) \
+        + spec.gated * 2 * el * spec.batch * spec.h * spec.w * spec.c \
+        + spec.pool * el * spec.batch * spec.out_h * spec.out_w * spec.k
     t = max(spec.flops / peak_flops, bts / hbm_bw)
     return Choice("xla", (), t, bts, spec.flops, 0)
 
@@ -111,13 +121,16 @@ def _candidates(spec: ConvSpec, epilogue=False):
     depthwise for grouped. ``epilogue=True`` adds the fused scale/bias
     loads (2·K elements — noise, but kept honest) to every candidate; the
     *unfused* penalty is charged to `xla_choice`, not here, since every
-    kernel family fuses in-kernel.
+    kernel family fuses in-kernel. A squeeze-and-excitation variant
+    (``pool``: depthwise; ``gated``: 1x1) exists only in its own family's
+    kernel and adds its fp32 sums or gate (``spec.se_bytes``) to the
+    output's bytes.
     """
     el = _el(spec)
     B, H, W, C, K, R, S = (spec.batch, spec.out_h, spec.out_w, spec.c,
                            spec.k, spec.r, spec.s)
     stride = spec.stride
-    out = B * H * W * K * el
+    out = B * H * W * K * el + spec.se_bytes
     ep = 2 * K * el if epilogue else 0  # fused scale+bias vector loads
     P = H * W
     cands = []
@@ -280,12 +293,20 @@ def measured_select(spec: ConvSpec, x=None, w=None, *, repeats=3,
     threaded to the kernels that take it (depthwise); ``ops.dispatch``
     drops it for the stride-1-only dense kernels.
     """
+    import jax
+    import jax.numpy as jnp
+
     from repro.kernels import ops
 
     if not tunable(spec):
         return xla_choice(spec, epilogue=epilogue)
     if x is None or w is None:
         x, w = _synth_inputs(spec)
+    se = {}  # a squeeze-and-excitation variant is timed as itself
+    if spec.pool:
+        se["pool"] = True
+    if spec.gated:
+        se["gate"] = jnp.ones((spec.batch, spec.c), jnp.float32)
 
     best = None
     timed: dict[tuple, float] = {}
@@ -293,13 +314,15 @@ def measured_select(spec: ConvSpec, x=None, w=None, *, repeats=3,
         if vmem > VMEM_BYTES:
             continue
         try:
-            ops.dispatch(algo, x, w, impl="pallas", stride=spec.stride,
-                         **dict(params)).block_until_ready()  # warm-up
+            jax.block_until_ready(ops.dispatch(
+                algo, x, w, impl="pallas", stride=spec.stride, **se,
+                **dict(params)))  # warm-up
             ts = []
             for _ in range(repeats):
                 t0 = time.perf_counter()
-                ops.dispatch(algo, x, w, impl="pallas", stride=spec.stride,
-                             **dict(params)).block_until_ready()
+                jax.block_until_ready(ops.dispatch(
+                    algo, x, w, impl="pallas", stride=spec.stride, **se,
+                    **dict(params)))
                 ts.append(time.perf_counter() - t0)
             t = min(ts)
         except Exception as e:
@@ -365,7 +388,15 @@ def _block_candidates(bspec: FusedBlockSpec, epilogue=True,
     candidate wins ties and the single-slab variant — whose projection
     reduction order is bitwise-identical to the per-layer chain — is
     preferred whenever it fits VMEM.
+
+    A block with a squeeze-and-excitation gate (``bspec.se``) gets no
+    candidate: the kernel accumulates the projection slab by slab, and a
+    gate made from every slab's pooled sums cannot reach the projection
+    inside that one pass. Its constituents run the SE variants of the
+    per-layer kernels instead.
     """
+    if bspec.se:
+        return []
     el = bspec.element_size
     constituents = block_constituents(bspec, epilogue=epilogue,
                                       peak_flops=peak_flops, hbm_bw=hbm_bw)
@@ -503,10 +534,10 @@ class TuningPlan:
                 for name, ch in self.block_choices.items()}
 
     def to_json(self) -> str:
-        layers = {name: {"spec": asdict(self.specs[name]),
+        layers = {name: {"spec": _spec_dict(self.specs[name]),
                          "choice": self.choices[name].to_dict()}
                   for name in self.specs}
-        blocks = {name: {"spec": asdict(self.block_specs[name]),
+        blocks = {name: {"spec": _spec_dict(self.block_specs[name]),
                          "choice": self.block_choices[name].to_dict()}
                   for name in self.block_specs}
         return json.dumps({"version": PLAN_VERSION, "mode": self.mode,
@@ -534,6 +565,16 @@ class TuningPlan:
     def load(cls, path) -> "TuningPlan":
         with open(path) as f:
             return cls.from_json(f.read())
+
+
+# squeeze-and-excitation fields of the keys: written only where set, so a
+# network without SE serializes its plan exactly as before they existed
+_SE_FIELDS = ("pool", "gated", "se")
+
+
+def _spec_dict(spec) -> dict:
+    return {k: v for k, v in asdict(spec).items()
+            if k not in _SE_FIELDS or v}
 
 
 def xla_fallback_plan(named_specs, mode: str = "cost_model") -> TuningPlan:

@@ -24,6 +24,12 @@ class ConvSpec:
     batch: int = 1
     dtype: str = "float32"
     groups: int = 1  # feature groups; groups == c == k is depthwise
+    # squeeze-and-excitation variants: ``pool`` — the conv also writes its
+    # output's (batch, k) fp32 spatial sums (the SE's squeeze; depthwise);
+    # ``gated`` — a (batch, c) gate scales the input's channels first (the
+    # SE's excitation; 1x1). Each is a kernel variant, so a tuning key.
+    pool: bool = False
+    gated: bool = False
 
     def __post_init__(self):
         assert self.c % self.groups == 0, (self.c, self.groups)
@@ -70,11 +76,19 @@ class ConvSpec:
 
     @property
     def bytes_min(self) -> int:
-        """Compulsory traffic: image in + filters in + output out."""
+        """Compulsory traffic: image in + filters in + output out, and a
+        squeeze-and-excitation variant's fp32 sums out or gate in."""
         el = self.element_size
         return el * (self.batch * self.h * self.w * self.c
                      + self.r * self.s * self.c_per_group * self.k
-                     + self.batch * self.out_h * self.out_w * self.k)
+                     + self.batch * self.out_h * self.out_w * self.k) \
+            + self.se_bytes
+
+    @property
+    def se_bytes(self) -> int:
+        """The fp32 (batch, k) sums a ``pool`` conv writes and the fp32
+        (batch, c) gate a ``gated`` conv reads."""
+        return 4 * self.batch * (self.k * self.pool + self.c * self.gated)
 
     @property
     def epilogue_bytes(self) -> int:
@@ -86,7 +100,7 @@ class ConvSpec:
             * self.out_w * self.k
 
     @classmethod
-    def from_tensors(cls, x, w, stride):
+    def from_tensors(cls, x, w, stride, *, pool=False, gated=False):
         """Derive the spec from real tensors (NHWC image, HWIO filters).
 
         Group-aware: grouped filters carry ``c // groups`` channels on their
@@ -99,7 +113,8 @@ class ConvSpec:
         assert c % c_per_group == 0, (
             f"image channels {c} not divisible by filter depth {c_per_group}")
         return cls(h=h, w=ww, c=c, k=k, r=r, s=s, stride=stride, batch=b,
-                   dtype=str(x.dtype), groups=c // c_per_group)
+                   dtype=str(x.dtype), groups=c // c_per_group, pool=pool,
+                   gated=gated)
 
 
 @dataclass(frozen=True)
@@ -120,6 +135,12 @@ class FusedBlockSpec:
         (``cin == mid`` by construction), ``r``/``s`` its filter size
         (3x3 for basic c2, 1x1 for bottleneck c3).
 
+    ``se`` is the width of an inverted residual's squeeze-and-excitation
+    gate (EfficientNet), 0 where the block has none. The fused kernel has
+    no SE, so the tuner never fuses a block with ``se`` set (see
+    ``autotune._block_candidates``); its constituents are the SE variants
+    of the per-layer kernels.
+
     ``h``/``w`` are the *input* spatial dims of the fused region. ``dtype``
     is part of the key exactly as for ``ConvSpec``: the saved-round-trip
     accounting scales with the element width, so a bf16 block tunes (and
@@ -137,9 +158,11 @@ class FusedBlockSpec:
     residual: bool = False
     batch: int = 1
     dtype: str = "float32"
+    se: int = 0
 
     def __post_init__(self):
         assert self.kind in ("inverted_residual", "residual_conv"), self.kind
+        assert not self.se or self.kind == "inverted_residual", self
         if self.kind == "residual_conv":
             assert self.stride == 1 and self.residual, self
             assert self.cin == self.mid, self
@@ -181,10 +204,10 @@ class FusedBlockSpec:
         parts.append(("dw", ConvSpec(
             h=self.h, w=self.w, c=self.mid, k=self.mid, r=self.r, s=self.s,
             stride=self.stride, groups=self.mid, batch=self.batch,
-            dtype=self.dtype)))
+            dtype=self.dtype, pool=bool(self.se))))
         parts.append(("pw2", ConvSpec(
             h=self.out_h, w=self.out_w, c=self.mid, k=self.cout, r=1, s=1,
-            batch=self.batch, dtype=self.dtype)))
+            batch=self.batch, dtype=self.dtype, gated=bool(self.se))))
         return tuple(parts)
 
     @property

@@ -312,6 +312,22 @@ class InferenceEngine:
         retraces."""
         return self._fwd_batch._cache_size()
 
+    def plan_counters(self) -> dict:
+        """What the plan runs, counted: conv ``sites``, sites on the XLA
+        escape hatch (``xla_sites``), blocks fused into one kernel
+        (``fused_sites``) and sites whose input a squeeze-and-excitation
+        gate scales (``gated_sites``) — integers for the ``engine.build``
+        span."""
+        plan = self.plan
+        if plan is None:
+            return {"sites": 0, "xla_sites": 0, "fused_sites": 0,
+                    "gated_sites": 0}
+        return {"sites": len(plan.choices),
+                "xla_sites": sum(c.algorithm == "xla"
+                                 for c in plan.choices.values()),
+                "fused_sites": len(plan.block_choices),
+                "gated_sites": sum(s.gated for s in plan.specs.values())}
+
     def traffic_report(self):
         """Per-layer bytes/flops for every planned conv site — the energy
         proxy (DESIGN.md §7.5). Coverage follows the model module's

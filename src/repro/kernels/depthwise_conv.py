@@ -23,6 +23,12 @@ multipliers > 1 are supported with lax's HWIO convention — filters
 repeating the input slab M× on lanes inside the kernel. An
 optional (scale, bias, act) epilogue folds BN + ReLU6 into the output
 write, same contract as the dense kernels.
+
+With ``pool=True`` (a squeeze-and-excitation block's depthwise conv) the
+epilogue also writes the slab's per-channel spatial sums, in fp32, as a
+second output: each grid step holds one channel slab of the whole image,
+so it holds every term of those sums. That variant is a pallas_call of
+its own name, ``depthwise_pool``, so a device trace tells it apart.
 """
 from __future__ import annotations
 
@@ -37,13 +43,15 @@ from repro.kernels.fusion import epilogue_operands
 from repro.kernels.ref import apply_act
 
 
-def _kernel(x_ref, w_ref, *refs, H, W, R, S, stride, mult, act, fused):
+def _kernel(x_ref, w_ref, *refs, H, W, R, S, stride, mult, act, fused,
+            pool):
     """x_ref: (1, stride², Hq, Wq, TC) padded image channel slab in its
     stride phases, VMEM-pinned.
     w_ref: (R, S, 1, TK) — the slab's per-channel filter taps (TK = M·TC).
-    refs: optional (scale, bias) (1, TK) slabs, then o_ref (1, H, W, TK).
+    refs: optional (scale, bias) (1, TK) slabs, then o_ref (1, H, W, TK),
+    then with ``pool`` s_ref (1, 1, TK), the slab's spatial sums.
     """
-    o_ref = refs[-1]
+    o_ref = refs[-2] if pool else refs[-1]
     TK = w_ref.shape[-1]
     acc = jnp.zeros((H, W, TK), jnp.float32)
     for r in range(R):          # static taps — fully unrolled, VPU-pipelined
@@ -57,14 +65,19 @@ def _kernel(x_ref, w_ref, *refs, H, W, R, S, stride, mult, act, fused):
         acc = acc * refs[0][0] + refs[1][0]
     acc = apply_act(acc, act)
     o_ref[0] = acc.astype(o_ref.dtype)
+    if pool:
+        refs[-1][0] = acc.sum(axis=0).sum(axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("stride", "block_c", "act", "interpret"))
+                   static_argnames=("stride", "block_c", "act", "pool",
+                                    "interpret"))
 def depthwise_conv(x_padded, w, *, stride: int = 1, block_c: int = 128,
-                   scale=None, bias=None, act=None, interpret: bool = False):
+                   scale=None, bias=None, act=None, pool: bool = False,
+                   interpret: bool = False):
     """x_padded: (B, Hp, Wp, C) pre-padded; w: (R, S, 1, M·C)
-    -> (B, H, W, M·C).
+    -> (B, H, W, M·C), and with ``pool`` also its (B, M·C) fp32 spatial
+    sums, taken after the epilogue.
 
     ``block_c`` tiles the *output*-channel axis (the tuned kernel
     parameter); the grid is (batch, channel blocks) and every operand of
@@ -94,12 +107,24 @@ def depthwise_conv(x_padded, w, *, stride: int = 1, block_c: int = 128,
         scale, bias, K, tk, lambda b, c: (0, c))
     operands += extra
     in_specs += extra_specs
-    return pl.pallas_call(
+    out_specs = pl.BlockSpec((1, H, W, tk), lambda b, c: (b, 0, 0, c))
+    out_shape = jax.ShapeDtypeStruct((B, H, W, K), x_padded.dtype)
+    if pool:
+        out_specs = (out_specs,
+                     pl.BlockSpec((1, 1, tk), lambda b, c: (b, 0, c)))
+        out_shape = (out_shape,
+                     jax.ShapeDtypeStruct((B, 1, K), jnp.float32))
+    out = pl.pallas_call(
         functools.partial(_kernel, H=H, W=W, R=R, S=S, stride=stride,
-                          mult=mult, act=act, fused=fused),
+                          mult=mult, act=act, fused=fused, pool=pool),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, W, tk), lambda b, c: (b, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, H, W, K), x_padded.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
+        name="depthwise_pool" if pool else None,
     )(*operands)
+    if pool:
+        y, sums = out
+        return y, sums[:, 0]
+    return out

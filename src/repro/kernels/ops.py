@@ -42,7 +42,8 @@ def _interp() -> bool:
 # ---- the conv algorithms (pre-padded inputs) -------------------------
 #
 # Shared epilogue contract: every wrapper takes optional ``scale``/``bias``
-# ((K,) folded-BN vectors) and ``act`` ('relu' | 'relu6' | None). On the
+# ((K,) folded-BN vectors) and ``act`` ('relu' | 'relu6' | 'swish' |
+# 'sigmoid' | None). On the
 # pallas path they are fused into the kernel's output write; on the jnp
 # path ``ref.apply_epilogue`` applies the identical math as XLA ops, so the
 # two impls stay numerically interchangeable. ``stride`` is call-site
@@ -99,30 +100,37 @@ def winograd(x_padded, w, *, impl="auto", u=None, scale=None, bias=None,
 # ---- the grouped family (MobileNet depthwise/pointwise) --------------
 
 def depthwise(x_padded, w, *, impl="auto", stride=1, block_c=128, scale=None,
-              bias=None, act=None):
+              bias=None, act=None, pool=False):
     """Depthwise conv: x (B,Hp,Wp,C) pre-padded, w (R,S,1,M·C)
     -> (B,H,W,M·C).
 
     ``stride`` is geometry, not a tuned parameter — it comes from the call
     site, while ``block_c`` comes from the tuner. Stride 1 and 2 run
     in-kernel (MobileNet downsamples inside depthwise layers); channel
-    multipliers M > 1 repeat the input slab on lanes in-kernel.
+    multipliers M > 1 repeat the input slab on lanes in-kernel. With
+    ``pool`` it returns ``(y, sums)``: the output and its (B, M·C) fp32
+    spatial sums, a squeeze-and-excitation block's squeeze.
     """
     if _use_pallas(impl):
         return _dw.depthwise_conv(x_padded, w, stride=stride,
                                   block_c=block_c, scale=scale, bias=bias,
-                                  act=act, interpret=_interp())
-    return ref.apply_epilogue(ref.depthwise_conv(x_padded, w, stride=stride),
-                              scale=scale, bias=bias, act=act)
+                                  act=act, pool=pool, interpret=_interp())
+    y = ref.apply_epilogue(ref.depthwise_conv(x_padded, w, stride=stride),
+                           scale=scale, bias=bias, act=act)
+    return (y, ref.channel_sums(y)) if pool else y
 
 
 def pointwise(x, w, *, impl="auto", stride=1, block_k=128, scale=None,
-              bias=None, act=None):
-    """1x1 conv: x (B,H,W,C) *unpadded*, w (1,1,C,K) -> (B,H',W',K)."""
+              bias=None, act=None, gate=None):
+    """1x1 conv: x (B,H,W,C) *unpadded*, w (1,1,C,K) -> (B,H',W',K).
+    ``gate`` (B, C) scales the input's channels first (a squeeze-and-
+    excitation block's excitation)."""
     if _use_pallas(impl):
         return _pw.pointwise_conv(x, w, stride=stride, block_k=block_k,
-                                  scale=scale, bias=bias, act=act,
+                                  scale=scale, bias=bias, act=act, gate=gate,
                                   interpret=_interp())
+    if gate is not None:
+        x = ref.gate_channels(x, gate)
     return ref.apply_epilogue(ref.pointwise_conv(x, w, stride=stride),
                               scale=scale, bias=bias, act=act)
 

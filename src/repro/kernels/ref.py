@@ -29,10 +29,12 @@ def conv2d_reference(x, w, *, stride=1, padding="SAME", groups=1):
 
 
 def apply_act(y, act):
-    """Apply a named activation ('relu' | 'relu6' | None).
+    """Apply a named activation ('relu' | 'relu6' | 'swish' | 'sigmoid' |
+    None).
 
     Shared by the Pallas kernels' fused epilogues (it is plain jnp, so it
-    traces inside a kernel body) and the jnp reference paths.
+    traces inside a kernel body) and the jnp reference paths. Swish is
+    x·sigmoid(x), EfficientNet's activation; sigmoid is its SE gate's.
     """
     if act is None:
         return y
@@ -40,7 +42,26 @@ def apply_act(y, act):
         return jnp.maximum(y, 0)
     if act == "relu6":
         return jnp.clip(y, 0.0, 6.0)
+    if act == "swish":
+        return y * jax.nn.sigmoid(y)
+    if act == "sigmoid":
+        return jax.nn.sigmoid(y)
     raise ValueError(f"unknown activation {act!r}")
+
+
+def channel_sums(y):
+    """(B, H, W, C) -> (B, C) fp32 spatial sums: the squeeze of a
+    squeeze-and-excitation gate, before its division by H·W."""
+    return y.astype(jnp.float32).sum(axis=(1, 2))
+
+
+def gate_channels(x, gate):
+    """Scale each channel of (B, H, W, C) ``x`` by the (B, C) ``gate`` in
+    fp32, cast back to the compute dtype: the excitation of a
+    squeeze-and-excitation block, as the gated pointwise kernel applies it
+    to its input tile."""
+    z = x.astype(jnp.float32) * gate.astype(jnp.float32)[:, None, None, :]
+    return z.astype(x.dtype)
 
 
 def apply_epilogue(y, scale=None, bias=None, act=None):

@@ -9,8 +9,11 @@ from repro.models import spec as pspec
 
 def cnn_module(cfg):
     """The CNN family module (forward / model_specs / conv_specs) for a
-    config — ``extra["arch"]`` routes; ResNet is the default."""
-    return mobilenet if cfg.extra.get("arch") == "mobilenet" else resnet
+    config — ``extra["arch"]`` routes: MobileNetV2 and EfficientNet are
+    the inverted-residual family; ResNet is the default."""
+    if cfg.extra.get("arch") in ("mobilenet", "efficientnet"):
+        return mobilenet
+    return resnet
 
 
 def model_specs(cfg):

@@ -144,18 +144,19 @@ def block_specs(cfg):
     return specs
 
 
-def _conv(p, x, stride, algorithm, site, plan, wu=None, act=None):
+def _conv(p, x, stride, algorithm, site, plan, wu=None, act=None, **se):
     """One conv site, under a named scope of its plan name ``site``:
     folded-BN scale/bias and the activation ride into the kernel as a
     fused epilogue (``algorithms.conv2d`` threads them to the dispatched
-    kernel's output write)."""
+    kernel's output write). ``se`` carries a squeeze-and-excitation
+    block's ``pool`` / ``gate`` operands to ``conv2d``."""
     from repro.core import algorithms
 
     with jax.named_scope(site):
         return algorithms.conv2d(x, p["w"], stride=stride, padding="SAME",
                                  algorithm=algorithm, choice=plan.get(site),
                                  scale=p["scale"], bias=p["bias"], act=act,
-                                 u=(wu or {}).get(site))
+                                 u=(wu or {}).get(site), **se)
 
 
 def _block(p, x, bottleneck, stride, algorithm, name="", plan=None, wu=None):

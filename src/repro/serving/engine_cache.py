@@ -175,7 +175,7 @@ class EngineCache:
                                         plan=plan)
             if rec is not None:
                 rec.add("engine.build", t0, time.perf_counter_ns(),
-                        spans.new_id())
+                        spans.new_id(), attrs=eng.plan_counters())
             with self._lock:
                 self.misses += 1
                 if degraded:

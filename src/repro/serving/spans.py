@@ -24,7 +24,9 @@ and a few integer attributes. The units:
   are the ids of its requests. ``engine.call`` carries the dispatch's
   counters: ``batch`` (real images), ``padded`` (the bucket) and
   ``h2d_bytes`` (image bytes sent from the host);
-* an **engine build** (``engine.build``): an id of its own.
+* an **engine build** (``engine.build``): an id of its own, and the built
+  plan's counters (``InferenceEngine.plan_counters``): ``sites``,
+  ``xla_sites``, ``fused_sites``, ``gated_sites``.
 
 Nothing is written while recording; ``Recorder.mark(name)`` stamps an
 instant, so that a caller can put these spans on another clock (a device

@@ -343,3 +343,84 @@ def test_full_network_fused_vs_per_layer_logits_bitwise(network):
                                     plan=_strip_blocks(eng.plan))
     np.testing.assert_array_equal(np.asarray(per_layer_eng.run(img)), fused)
     assert not np.isnan(fused).any()
+
+
+# ----------------------------------------------------------------------
+# squeeze-and-excitation blocks (EfficientNet) and the networks without
+
+
+def test_se_blocks_never_get_a_fused_block():
+    """The fused inverted-residual kernel has no SE: no EfficientNet-B0
+    block site gets a candidate, though the same geometry without the SE
+    fuses; the plan keeps every block per-layer, on the SE variants."""
+    from repro.core.autotune import _block_candidates
+    from repro.models.registry import cnn_module
+
+    cfg = get("efficientnet_b0")
+    model = cnn_module(cfg)
+    sites = model.block_specs(cfg)
+    assert len(sites) == 16 and all(b.se for _, b in sites)
+    fusible = 0
+    for _, bspec in sites:
+        assert _block_candidates(bspec) == []
+        assert select_block(bspec) is None
+        dw, pw2 = bspec.conv_specs()[-2:]
+        assert dw[1].pool and pw2[1].gated
+        fusible += select_block(dataclasses.replace(bspec, se=0)) is not None
+    assert fusible > 0
+    plan = build_plan(model.conv_specs(cfg), epilogue=True,
+                      block_specs=sites)
+    assert plan.block_choices == {}
+    again = TuningPlan.from_json(plan.to_json())
+    assert again.specs == plan.specs and again.choices == plan.choices
+
+
+# The parent's MobileNetV2 at 224x224, before the family module learned
+# EfficientNet: sha256 of its tuned plan's JSON and of its parameter tree
+# ((path, shape, init) per leaf)
+MOBILENET_V2_PLAN_SHA = \
+    "35860a378c03f474877c1a165191e6a5da62e6d990f14af93ce567f4e584d998"
+MOBILENET_V2_TREE_SHA = \
+    "d12de10b4be0f17d3268921d93de66a03122cb9f85b6e62739ce58359a48cfdb"
+
+
+def test_mobilenet_v2_plan_and_param_tree_unchanged():
+    import hashlib
+
+    from repro.models.registry import cnn_module
+    from repro.models.spec import _walk
+
+    cfg = get("mobilenet_v2")
+    model = cnn_module(cfg)
+    plan = build_plan(model.conv_specs(cfg), epilogue=True,
+                      block_specs=model.block_specs(cfg))
+    assert '"pool"' not in plan.to_json() and '"se"' not in plan.to_json()
+    assert hashlib.sha256(plan.to_json().encode()).hexdigest() \
+        == MOBILENET_V2_PLAN_SHA
+    tree = [(p, s.shape, s.init) for p, s in _walk(model.model_specs(cfg))]
+    assert hashlib.sha256(repr(tree).encode()).hexdigest() \
+        == MOBILENET_V2_TREE_SHA
+    assert len(plan.block_choices) == 17
+
+
+@pytest.mark.parametrize("act", ["swish", "sigmoid"])
+@pytest.mark.parametrize("kind", ["inverted_residual", "residual_conv"])
+def test_fused_blocks_take_swish_and_sigmoid(kind, act):
+    """Both block kernels apply the swish / sigmoid epilogue as their
+    composed per-layer references do."""
+    x = jax.random.normal(KEY, (1, 8, 8, 8))
+    if kind == "inverted_residual":
+        w = _ir_weights(8, 48, 8, "float32")
+        want = ref.fused_inverted_residual(x, w, residual=True, act=act)
+        y = ops.fused_inverted_residual(x, w, impl="pallas", residual=True,
+                                        act=act)
+    else:
+        ks = jax.random.split(KEY, 3)
+        w = {"w": jax.random.normal(ks[0], (3, 3, 8, 8)) * 0.2,
+             "scale": jax.random.normal(ks[1], (8,)) * 0.5 + 1.0,
+             "bias": jax.random.normal(ks[2], (8,)) * 0.1}
+        xp = ref.pad_same(x, 3, 3)
+        want = ref.fused_residual_conv(xp, w, res=x, act=act)
+        y = ops.fused_residual_conv(xp, w, impl="pallas", res=x, act=act)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(want).max()))

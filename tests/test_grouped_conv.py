@@ -85,6 +85,63 @@ def test_depthwise_pallas_vs_structural_ref():
                                rtol=1e-5, atol=1e-5)
 
 
+# (H, W, C, kernel, stride, block_c): EfficientNet's 5x5 depthwise convs at
+# stride 1 and 2 cut to size (odd sizes, a ragged last lane slab, several
+# slabs), and a 3x3 one, each also writing its output's spatial sums
+DW_POOL_CASES = [(16, 16, 24, 5, 1, 128), (15, 13, 40, 5, 2, 128),
+                 (14, 14, 144, 5, 2, 128), (12, 12, 48, 5, 1, 16),
+                 (9, 9, 200, 3, 2, 128)]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+@pytest.mark.parametrize("case", DW_POOL_CASES, ids=str)
+def test_depthwise_pool_vs_lax_conv_and_mean(case, impl):
+    """The squeeze-and-excitation variant: the depthwise conv with its
+    swish epilogue, and its spatial sums over H·W equal to the mean of
+    ``lax.conv`` + epilogue (Pallas in interpret mode)."""
+    h, w, c, k, stride, block_c = case
+    x = jax.random.normal(KEY, (2, h, w, c))
+    wgt = jax.random.normal(jax.random.fold_in(KEY, k), (k, k, 1, c)) * 0.3
+    sc = 1 + 0.1 * jax.random.normal(jax.random.fold_in(KEY, 3), (c,))
+    bi = 0.1 * jax.random.normal(jax.random.fold_in(KEY, 4), (c,))
+    want = ref.apply_epilogue(
+        ref.conv2d_reference(x, wgt, stride=stride, groups=c),
+        scale=sc, bias=bi, act="swish")
+    xp = ref.pad_same(x, k, k, stride=stride)
+    y, sums = ops.depthwise(xp, wgt, impl=impl, stride=stride,
+                            block_c=block_c, scale=sc, bias=bi, act="swish",
+                            pool=True)
+    assert sums.shape == (2, c) and sums.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-4 * float(jnp.abs(want).max()))
+    np.testing.assert_allclose(np.asarray(sums) / (y.shape[1] * y.shape[2]),
+                               np.asarray(want.mean(axis=(1, 2))),
+                               rtol=1e-4, atol=1e-5)
+
+
+# (H, W, C, K, block_k): EfficientNet's gated projections cut to size
+GATED_CASES = [(8, 8, 24, 16, 128), (7, 7, 96, 40, 16), (5, 9, 130, 24, 128)]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+@pytest.mark.parametrize("case", GATED_CASES, ids=str)
+def test_gated_pointwise_vs_plain_jnp(case, impl):
+    """The pointwise kernel with a per-image, per-input-channel gate on its
+    input (an SE block's excitation) against plain jnp."""
+    h, w, c, k, block_k = case
+    x = jax.random.normal(KEY, (2, h, w, c))
+    wgt = jax.random.normal(jax.random.fold_in(KEY, 1), (1, 1, c, k))
+    gate = jax.random.uniform(jax.random.fold_in(KEY, 2), (2, c))
+    sc = 1 + 0.1 * jax.random.normal(jax.random.fold_in(KEY, 3), (k,))
+    bi = 0.1 * jax.random.normal(jax.random.fold_in(KEY, 4), (k,))
+    want = jnp.einsum("bhwc,ck->bhwk", x * gate[:, None, None, :],
+                      wgt[0, 0], precision="highest") * sc + bi
+    y = ops.pointwise(x, wgt, impl=impl, block_k=block_k, scale=sc,
+                      bias=bi, gate=gate)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-4 * float(jnp.abs(want).max()))
+
+
 @pytest.mark.parametrize("ck", [(8, 16), (48, 24), (96, 576), (130, 40)])
 def test_pointwise_kernel_vs_ground_truth(ck):
     c, k = ck
@@ -162,6 +219,41 @@ def test_tuner_on_grouped_specs():
         ConvSpec(h=16, w=16, c=96, k=96, groups=4)).algorithm == "xla"
 
 
+def test_tuner_keys_and_costs_the_se_variants():
+    """A depthwise conv that writes its sums (``pool``) and a 1x1 conv
+    whose input a gate scales (``gated``) are tuning keys of their own,
+    costed with their fp32 sums / gate, and only their own kernel family
+    carries them; every EfficientNet-B0 site at 224x224 gets a kernel."""
+    from repro.core.autotune import select, tunable, xla_choice
+    from repro.models.registry import cnn_module
+
+    dw = ConvSpec(h=28, w=28, c=240, k=240, r=5, s=5, groups=240)
+    pw = ConvSpec(h=28, w=28, c=240, k=40, r=1, s=1)
+    for plain, se, extra in ((dw, ConvSpec(**{**dw.__dict__, "pool": True}),
+                              4 * 240),
+                             (pw, ConvSpec(**{**pw.__dict__, "gated": True}),
+                              4 * 240)):
+        assert se != plain and hash(se) != hash(plain)
+        a, b = select(plain, epilogue=True), select(se, epilogue=True)
+        assert a.algorithm == b.algorithm in ("depthwise", "pointwise")
+        assert b.est_bytes == a.est_bytes + extra == a.est_bytes \
+            + se.se_bytes
+        assert xla_choice(se, epilogue=True).est_bytes \
+            > xla_choice(plain, epilogue=True).est_bytes + extra
+    cfg = get("efficientnet_b0")
+    specs = cnn_module(cfg).conv_specs(cfg)
+    assert all(tunable(s) for _, s in specs)
+    algos = {n: select(s, epilogue=True).algorithm for n, s in specs}
+    assert {a for n, a in algos.items() if n.endswith(".dw")} \
+        == {"depthwise"}
+    assert {a for n, a in algos.items() if ".pw" in n or n == "head"} \
+        == {"pointwise"}
+    assert sum(s.pool for _, s in specs) == sum(s.gated for _, s in specs) \
+        == 16
+    assert {(s.r, s.stride) for _, s in specs if s.pool} \
+        == {(3, 1), (3, 2), (5, 1), (5, 2)}
+
+
 def test_mobilenet_tuned_plan_end_to_end(monkeypatch):
     """The acceptance path: a MobileNet-style forward runs through a tuned
     plan (cost-model mode) where fused inverted-residual blocks dispatch
@@ -212,3 +304,47 @@ def test_mobilenet_tuned_plan_end_to_end(monkeypatch):
     np.testing.assert_allclose(np.asarray(logits),
                                np.asarray(ref_eng.run(img)),
                                rtol=1e-3, atol=1e-3)
+
+
+def test_efficientnet_tuned_plan_end_to_end(monkeypatch):
+    """EfficientNet through a tuned plan: no block is fused (each has an
+    SE), every depthwise site runs the depthwise kernel with ``pool`` and
+    every projection the pointwise kernel with a gate, once each, under
+    the SE's named scope in between; the logits match the all-XLA route,
+    single and batched, in Pallas interpret mode."""
+    from repro.models import mobilenet
+
+    cfg = tiny_variant(get("efficientnet_b0"))
+    monkeypatch.setattr(ops, "_use_pallas", lambda impl: impl != "jnp")
+    calls = []
+    for name in ("depthwise", "pointwise"):
+        fn = ops.ALGORITHMS[name]
+
+        def wrapper(x, w, *, _name=name, _fn=fn, **params):
+            calls.append((_name, params.get("pool", False),
+                          params.get("gate") is not None))
+            return _fn(x, w, **params)
+        monkeypatch.setitem(ops.ALGORITHMS, name, wrapper)
+    eng = InferenceEngine(cfg)
+    assert not eng.plan.block_choices
+    assert eng.plan_counters() == {"sites": 13, "xla_sites": 0,
+                                   "fused_sites": 0, "gated_sites": 4}
+    img = jax.random.normal(KEY, (32, 32, 3))
+    logits = eng.run(img)
+    assert sorted(calls) == sorted(
+        [("depthwise", True, False)] * 4 + [("pointwise", False, True)] * 4
+        + [("pointwise", False, False)] * 4)  # 3 expansions + the head
+    hlo = jax.jit(lambda p, x: mobilenet.forward(
+        p, cfg, x, plan=eng.plan.choices)).lower(
+            eng.params, img[None]).as_text(debug_info=True)
+    assert "s1b0.se/" in hlo
+    ref_eng = InferenceEngine(cfg, params=eng.params, algorithm="xla")
+    want = np.asarray(ref_eng.run(img))
+    np.testing.assert_allclose(np.asarray(logits), want, rtol=1e-4,
+                               atol=1e-6)
+    rows = eng.run_batch([img, img * 0.5])
+    np.testing.assert_allclose(np.asarray(rows[0]), want, rtol=1e-4,
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(rows[1]), np.asarray(ref_eng.run(img * 0.5)),
+        rtol=1e-4, atol=1e-6)

@@ -126,3 +126,29 @@ def test_a_padded_batch_of_three_is_one_dispatch_of_three_requests(
     assert sorted(s.id for s in got if s.name == "serve.request") \
         == sorted(ids)
     assert {s.id for s in got if s.parents} == {call.id}
+
+
+@pytest.mark.parametrize("network,counters", [
+    ("efficientnet_b0", {"sites": 13, "xla_sites": 0, "fused_sites": 0,
+                         "gated_sites": 4}),
+    ("mobilenet_v2", {"sites": 13, "xla_sites": 0, "fused_sites": 4,
+                      "gated_sites": 0})])
+def test_engine_build_carries_the_plans_counters(recording_off, network,
+                                                 counters):
+    rec = spans.start()
+    with Server(tiny=True) as server:
+        server.warm(network)
+    got = rec.stop()
+    (build,) = [s for s in got if s.name == "engine.build"]
+    assert build.attrs == counters
+
+
+def test_engine_counters_at_published_size():
+    """EfficientNet-B0 at 224x224: every site on a kernel, the SE of all
+    16 blocks gated, no block fused."""
+    from repro.configs import get
+    from repro.core import InferenceEngine
+
+    eng = InferenceEngine(get("efficientnet_b0"), params={})
+    assert eng.plan_counters() == {"sites": 49, "xla_sites": 0,
+                                   "fused_sites": 0, "gated_sites": 16}

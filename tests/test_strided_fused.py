@@ -135,7 +135,7 @@ EPILOGUE_ALGOS = ["ilpm", "direct", "im2col", "libdnn", "winograd"]
 
 
 @pytest.mark.parametrize("algo", EPILOGUE_ALGOS)
-@pytest.mark.parametrize("act", [None, "relu", "relu6"])
+@pytest.mark.parametrize("act", [None, "relu", "relu6", "swish", "sigmoid"])
 def test_dense_epilogue_fusion_parity(algo, act):
     """conv+scale+bias+act fused in-kernel == unfused reference (fp32)."""
     x, wgt = _mk(1, 12, 12, 8, 16)
@@ -153,7 +153,8 @@ def test_dense_epilogue_fusion_parity(algo, act):
             err_msg=f"{algo}/{impl}")
 
 
-def test_grouped_epilogue_fusion_parity():
+@pytest.mark.parametrize("act", ["relu6", "swish", "sigmoid"])
+def test_grouped_epilogue_fusion_parity(act):
     x = jax.random.normal(KEY, (1, 10, 10, 12))
     dw = jax.random.normal(jax.random.fold_in(KEY, 5), (3, 3, 1, 12))
     pw = jax.random.normal(jax.random.fold_in(KEY, 6), (1, 1, 12, 20))
@@ -163,9 +164,9 @@ def test_grouped_epilogue_fusion_parity():
         sc = jax.random.normal(jax.random.fold_in(KEY, k), (k,))
         bi = jax.random.normal(jax.random.fold_in(KEY, k + 1), (k,))
         xin = ref.pad_same(x, 3, 3) if algo == "depthwise" else x
-        want = ref.apply_epilogue(gt, scale=sc, bias=bi, act="relu6")
+        want = ref.apply_epilogue(gt, scale=sc, bias=bi, act=act)
         y = ops.ALGORITHMS[algo](xin, w, impl="pallas", scale=sc, bias=bi,
-                                 act="relu6")
+                                 act=act)
         np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                    rtol=2e-4, atol=1e-3, err_msg=algo)
 

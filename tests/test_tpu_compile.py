@@ -6,8 +6,8 @@ chip's compiler (Mosaic) would raise — misaligned slices, lowerings it does
 not implement, too much VMEM. Interpret mode, which the other kernel tests
 use, can show none of that.
 
-The shapes are sites of ResNet-18 and MobileNetV2 at 224x224, with the
-tuning parameters the cost model picks there. The strided ones include
+The shapes are sites of ResNet-18, MobileNetV2 and EfficientNet-B0 at
+224x224, with the tuning parameters the cost model picks there. The strided ones include
 every pattern Mosaic refused before the kernels read strided taps from
 stride phases (``repro.kernels.phases``): a strided read of a tile wider
 than 128 lanes, and a strided slice of a loaded value.
@@ -113,6 +113,38 @@ def test_depthwise_stride2_compiles(chip, h, c):
     hlo = _compile_conv(depthwise_conv.depthwise_conv, chip, (1, hp, hp, c),
                         (3, 3, 1, c), stride=2, block_c=128, act="relu6")
     assert "tpu_custom_call" in hlo
+
+
+# (H, C): EfficientNet-B0's 5x5/2 depthwise sites s2b0.dw and s5b0.dw, in
+# the squeeze-and-excitation variant that also writes the spatial sums
+DEPTHWISE_POOL = [(56, 144), (14, 672)]
+
+
+@pytest.mark.parametrize("h,c", DEPTHWISE_POOL,
+                         ids=[f"{h}x{h}x{c}_5x5s2" for h, c in DEPTHWISE_POOL])
+def test_depthwise_pool_5x5_stride2_compiles(chip, h, c):
+    hp = _padded(h, 5, 2)
+    hlo = _compile_conv(depthwise_conv.depthwise_conv, chip, (1, hp, hp, c),
+                        (5, 5, 1, c), stride=2, block_c=128, act="swish",
+                        pool=True)
+    assert "%depthwise_pool" in hlo and "tpu_custom_call" in hlo
+
+
+# (H, C, K): EfficientNet-B0's gated projections s0b0.pw2 and s1b0.pw2
+GATED = [(112, 32, 16), (56, 96, 24)]
+
+
+@pytest.mark.parametrize("h,c,k", GATED,
+                         ids=[f"{h}x{h}x{c}-{k}" for h, c, k in GATED])
+def test_gated_pointwise_compiles(chip, h, c, k):
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=chip)
+
+    hlo = pointwise_conv.pointwise_conv.lower(
+        sds((1, h, h, c)), sds((1, 1, c, k)), scale=sds((k,)),
+        bias=sds((k,)), gate=sds((1, c)), block_k=128,
+        interpret=False).compile().as_text()
+    assert "%pointwise_gated" in hlo and "tpu_custom_call" in hlo
 
 
 def test_fused_residual_conv_compiles(chip):
