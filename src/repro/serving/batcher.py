@@ -13,6 +13,12 @@ goes to the device when it fills, or when the window measured from its
 a long dispatch goes out the moment the engine frees up, never paying a
 fresh window on top of the wait (the continuous-batching property; the
 deadline-window design it replaces restarted the window at dequeue).
+A **lone** request skips the window: one that finds nothing pending and
+no dispatch of this batcher in flight, after ``LONE_STREAK`` such
+arrivals in a row, while the shared device scheduler has nothing queued
+or running. A closed-loop caller alone never sends a partner, so the
+window would only delay its answer; any overlapping arrival resets the
+streak, and the window then works exactly as above.
 Dispatch shape — one device program a dispatch, the engine's jitted
 entry; no eager JAX operation runs before or after it:
 
@@ -86,6 +92,8 @@ from repro.serving.resilience import (
 log = logging.getLogger("repro.serving")
 
 DISPATCH_LOG = 1024  # recent dispatches that stats()' latencies are over
+LONE_STREAK = 8  # lone arrivals in a row before a lone request skips the
+#                  window: a fresh batcher, or a burst's sender, still waits
 
 
 def bucket(n: int, max_batch: int) -> int:
@@ -144,7 +152,7 @@ class MicroBatcher:
         # the dispatch path appends to the dispatch log while stats()
         # reads it from caller threads: every access takes this lock
         self._stats_lock = threading.Lock()
-        self._causes = {"full": 0, "window": 0, "drain": 0}
+        self._causes = {"full": 0, "window": 0, "lone": 0, "drain": 0}
         self._shed = {"overload": 0, "deadline": 0, "cancelled": 0,
                       "breaker": 0}
         self._retries = 0
@@ -156,6 +164,8 @@ class MicroBatcher:
         # admission bound is exact; the loop thread is the only consumer.
         self._cond = threading.Condition()
         self._pending: deque[Request] = deque()
+        self._in_flight = False  # a batch taken and not yet resolved
+        self._lone = 0           # lone arrivals in a row (no overlap seen)
         self._closed = False
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=f"microbatcher-{id(self):x}")
@@ -192,6 +202,10 @@ class MicroBatcher:
                     f"waiting); request shed at admission")
             if self._pending:  # mid-flight: joins the forming batch
                 self._joined += 1
+            if self._pending or self._in_flight:
+                self._lone = 0
+            else:
+                self._lone += 1
             self._pending.append(req)
             self._cond.notify()
         return req
@@ -250,8 +264,9 @@ class MicroBatcher:
                 # request's arrival — a batch that formed while the
                 # previous dispatch held the device goes out immediately
                 window_end = self._pending[0].arrival + self.window_s
+                lone = self._skips_window()
                 while len(self._pending) < self.max_batch \
-                        and not self._closed:
+                        and not self._closed and not lone:
                     wait = window_end - time.perf_counter()
                     if wait <= 0:
                         break
@@ -259,13 +274,16 @@ class MicroBatcher:
                 take = min(len(self._pending), self.max_batch)
                 raw = [self._pending.popleft() for _ in range(take)]
                 drain = self._closed
+                self._in_flight = True
             if rec is not None:
                 t_take = time.perf_counter_ns()
             batch = [r for r in raw if self._take(r)]
-            if not batch:
-                continue  # everything shed at dequeue: no dispatch
+            if not batch:  # everything shed at dequeue: no dispatch
+                self._landed()
+                continue
             cause = ("drain" if drain
                      else "full" if len(raw) >= self.max_batch
+                     else "lone" if lone
                      else "window")
             with self._stats_lock:
                 self._causes[cause] += 1
@@ -273,11 +291,28 @@ class MicroBatcher:
             if rec is not None:
                 span = spans.Dispatch(rec, spans.new_id(),
                                       tuple(r.id for r in batch))
-                span.add("batcher.window", t_window, t_take)
+                span.add("batcher.window", t_window, t_take,
+                         {"cause": cause})
                 for r in batch:
                     rec.add("batcher.wait", spans.ns(r.arrival), t_take,
                             r.id)
             self._dispatch(batch, span)
+
+    def _skips_window(self) -> bool:
+        """Under ``_cond``: the one pending request is lone, the last
+        ``LONE_STREAK`` arrivals were too, and nothing else holds or waits
+        for the device, so no partner can come and no dispatch is ahead."""
+        return (len(self._pending) == 1 and self.max_batch > 1
+                and self._lone >= LONE_STREAK
+                and (self._scheduler is None
+                     or not self._scheduler.busy()))
+
+    def _landed(self) -> None:
+        """The taken batch is no longer in flight: called before its first
+        request resolves, so a caller that resubmits from its answer finds
+        nothing in flight and counts as lone."""
+        with self._cond:
+            self._in_flight = False
 
     # ------------------------------------------------------------------
     # dispatch with retry / breaker / degraded-mode fallback
@@ -392,9 +427,11 @@ class MicroBatcher:
             else:
                 outs, padded = self._attempt(batch, span)
         except Exception as e:  # resolve, don't kill the loop
+            self._landed()
             for r in batch:
                 req_mod.fail(r, e)
             return
+        self._landed()
         if span is not None:
             t0 = time.perf_counter_ns()
         for r, o in zip(batch, outs):
@@ -421,9 +458,9 @@ class MicroBatcher:
         life; latency mean/p50/p95/max (seconds, submit -> future
         resolution) and deadline misses over the last ``DISPATCH_LOG``
         dispatches; live queue depth, mid-flight joins, dispatch causes
-        (full batch vs expired window vs shutdown drain), and the
-        resilience counters (sheds by cause, retries, breaker state,
-        degraded-mode swaps)."""
+        (full batch vs expired window vs a lone request that skipped the
+        window vs shutdown drain), and the resilience counters (sheds by
+        cause, retries, breaker state, degraded-mode swaps)."""
         with self._cond:
             depth = len(self._pending)
             joined = self._joined

@@ -62,6 +62,7 @@ class DeviceScheduler:
         self._heap: list[tuple[tuple, int, _Job]] = []
         self._seq = itertools.count()  # FIFO tie-break inside one key
         self._closed = False
+        self._running = False  # the device thread is inside a job
         self._completed: dict[str, int] = {}  # network -> jobs finished
         self._depth_high_water = 0
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -109,6 +110,7 @@ class DeviceScheduler:
                 if not self._heap and self._closed:
                     return
                 _key, _seq, job = heapq.heappop(self._heap)
+                self._running = True
             if job.span is not None:
                 job.span.add("scheduler.queue", job.t_enqueued,
                              time.perf_counter_ns())
@@ -119,6 +121,7 @@ class DeviceScheduler:
             if job.span is not None:
                 job.t_done = time.perf_counter_ns()
             with self._cond:
+                self._running = False
                 self._completed[job.network] = \
                     self._completed.get(job.network, 0) + 1
             job.done.set()
@@ -141,6 +144,11 @@ class DeviceScheduler:
 
     def __exit__(self, *exc):
         self.close()
+
+    def busy(self) -> bool:
+        """A job is queued or running: a dispatch handed over now waits."""
+        with self._cond:
+            return self._running or bool(self._heap)
 
     def stats(self) -> dict:
         with self._cond:

@@ -13,7 +13,7 @@ lock. ``start()`` turns recording on and returns the ``Recorder``;
 
 A ``Span`` is a name, its start and end on ``time.perf_counter_ns()``,
 the id of the unit it belongs to, the ids of the units that caused it,
-and a few integer attributes. The units:
+and a few attributes. The units:
 
 * a **request** (``serve.request``, ``batcher.wait``): its
   ``Request.id``; no parents;
@@ -21,9 +21,11 @@ and a few integer attributes. The units:
   ``engine.inputs``, ``engine.call``, ``engine.outputs``,
   ``engine.ready``, ``scheduler.return``, ``batcher.resolve``,
   ``engine.first_call``): an id drawn when the batch is taken; its parents
-  are the ids of its requests. ``engine.call`` carries the dispatch's
-  counters: ``batch`` (real images), ``padded`` (the bucket) and
-  ``h2d_bytes`` (image bytes sent from the host);
+  are the ids of its requests. ``batcher.window`` carries why the batch
+  was taken: ``cause`` (``full``, ``window``, ``lone`` or ``drain``).
+  ``engine.call`` carries the dispatch's counters: ``batch`` (real
+  images), ``padded`` (the bucket) and ``h2d_bytes`` (image bytes sent
+  from the host);
 * an **engine build** (``engine.build``): an id of its own, and the built
   plan's counters (``InferenceEngine.plan_counters``): ``sites``,
   ``xla_sites``, ``fused_sites``, ``gated_sites``.

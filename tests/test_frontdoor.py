@@ -6,9 +6,11 @@ Batcher-level tests drive ``MicroBatcher`` with stub engines (dispatch
 timing is the subject, not convolution), so they are fast and
 deterministic; API tests use real tiny networks where numerics matter.
 """
+import contextlib
 import threading
 import time
 import warnings
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -21,7 +23,9 @@ from repro.serving import (
     Server,
     ServingOptions,
     Ticket,
+    spans,
 )
+from repro.serving.batcher import LONE_STREAK
 
 
 class FakeEngine:
@@ -131,6 +135,211 @@ def test_bitwise_equal_to_sequential_with_mid_flight_admission():
     for g, s in zip(got, seq):
         np.testing.assert_array_equal(g, s)
     assert b.stats()["joined_forming"] >= 1  # coalescing actually happened
+
+
+# ---------------------------------------------------------------------------
+# a lone request skips the window
+
+
+@pytest.mark.parametrize("scheduled", [False, True],
+                         ids=["inline", "scheduled"])
+def test_lone_closed_loop_caller_skips_the_window_after_the_streak(
+        scheduled):
+    """One caller that waits on each answer never sends a partner: from
+    the LONE_STREAK-th lone arrival on, each request dispatches at once
+    instead of waiting out the 200 ms window."""
+    engine = FakeEngine()
+    n = 20
+    with contextlib.ExitStack() as stack:
+        sched = (stack.enter_context(DeviceScheduler()) if scheduled
+                 else None)
+        b = stack.enter_context(MicroBatcher(
+            engine, max_batch=8, window_ms=200.0, scheduler=sched))
+        tickets = []
+        for i in range(n):
+            t = b.submit(_img(i))
+            t.result(timeout=10)
+            tickets.append(t)
+    assert engine.batches == [1] * n
+    assert b.stats()["dispatch_causes"] == {
+        "full": 0, "window": LONE_STREAK - 1,
+        "lone": n - LONE_STREAK + 1, "drain": 0}
+    for t in tickets[:LONE_STREAK - 1]:
+        assert t.latency >= 0.19, "a request before the streak skipped"
+    for t in tickets[LONE_STREAK - 1:]:
+        assert t.latency < 0.1, f"lone request waited {t.latency:.3f}s"
+
+
+def test_resubmitting_from_the_answer_still_counts_as_lone():
+    """A caller that sends its next request from the callback handing it
+    the answer (on the batcher's thread, while the dispatch resolves)
+    finds nothing in flight: the in-flight flag clears before the first
+    request resolves, so such a chain reaches the streak too."""
+    engine = FakeEngine()
+    n = LONE_STREAK + 4
+    tickets = []
+    done = threading.Event()
+    with MicroBatcher(engine, max_batch=8, window_ms=20.0) as b:
+        def send():
+            t = b.submit(_img(len(tickets)))
+            tickets.append(t)
+            t.add_done_callback(
+                lambda _t: send() if len(tickets) < n else done.set())
+
+        send()
+        assert done.wait(10)
+    assert len(tickets) == n
+    assert b.stats()["dispatch_causes"] == {
+        "full": 0, "window": LONE_STREAK - 1,
+        "lone": n - LONE_STREAK + 1, "drain": 0}
+
+
+@pytest.mark.parametrize("joiners", [1, 2])
+def test_a_burst_after_the_streak_resets_it_and_coalesces_again(joiners):
+    """After the streak, a burst's first request goes out alone; the ones
+    that arrive during its dispatch reset the streak, even one with
+    nothing pending beside it, and share the next dispatch; the burst
+    after that coalesces under the window."""
+    engine = FakeEngine()
+    with MicroBatcher(engine, max_batch=8, window_ms=50.0) as b:
+        for i in range(LONE_STREAK):
+            b.submit(_img(i)).result(timeout=10)
+        engine.service_s = 0.1
+        first = b.submit(_img(0))  # lone: dispatches at once
+        deadline = time.perf_counter() + 5
+        while len(engine.batches) <= LONE_STREAK \
+                and time.perf_counter() < deadline:
+            time.sleep(0.001)
+        rest = [b.submit(_img(i + 1)) for i in range(joiners)]
+        for t in [first, *rest]:
+            t.result(timeout=10)
+        burst = [b.submit(_img(i)) for i in range(3)]
+        for t in burst:
+            t.result(timeout=10)
+    assert [d["batch"] for d in b.dispatches][LONE_STREAK:] \
+        == [1, joiners, 3]
+    assert b.stats()["dispatch_causes"] == {
+        "full": 0, "window": LONE_STREAK - 1 + 2, "lone": 2, "drain": 0}
+
+
+def test_a_busy_device_keeps_the_window():
+    """While another network's jobs hold the shared device, a lone
+    request would queue anyway: it waits out the window as before, so no
+    dispatch is lone. Once the device is free, the next one is."""
+    engine = FakeEngine()
+    stop = threading.Event()
+    with DeviceScheduler() as sched:
+        def feed():  # two feeders: one job running, the next queued
+            while not stop.is_set():
+                sched.run(lambda: time.sleep(0.03),
+                          urgency=time.perf_counter(), network="slow")
+
+        feeders = [threading.Thread(target=feed, daemon=True)
+                   for _ in range(2)]
+        for t in feeders:
+            t.start()
+        deadline = time.perf_counter() + 5
+        while sched.stats()["jobs"] < 2 and time.perf_counter() < deadline:
+            time.sleep(0.001)
+        with MicroBatcher(engine, max_batch=8, window_ms=20.0,
+                          scheduler=sched, name="fast") as b:
+            try:
+                for i in range(LONE_STREAK + 4):
+                    t = b.submit(_img(i))
+                    t.result(timeout=10)
+                    assert t.latency >= 0.019, "skipped the window"
+                while_busy = dict(b.stats()["dispatch_causes"])
+            finally:
+                stop.set()
+                for t in feeders:
+                    t.join(10)
+            assert not sched.busy()
+            b.submit(_img(0)).result(timeout=10)
+    assert while_busy == {"full": 0, "window": LONE_STREAK + 4,
+                          "lone": 0, "drain": 0}
+    assert b.stats()["dispatch_causes"]["lone"] == 1
+
+
+def test_batcher_window_span_carries_its_cause():
+    """The ``batcher.window`` span says why its batch was taken, so a
+    reader can tell the lone path from the window."""
+    assert spans.active is None
+    rec = spans.start()
+    try:
+        with MicroBatcher(FakeEngine(), max_batch=8, window_ms=10.0) as b:
+            for i in range(LONE_STREAK + 2):
+                b.submit(_img(i)).result(timeout=10)
+    finally:
+        got = rec.stop()
+    causes = [s.attrs["cause"] for s in got if s.name == "batcher.window"]
+    assert causes == ["window"] * (LONE_STREAK - 1) + ["lone"] * 3
+    assert Counter(causes) == Counter(b.stats()["dispatch_causes"])
+
+
+def test_lone_bookkeeping_under_contention():
+    """Closed-loop callers, more than cores, with a short switch interval:
+    every request gets its own answer, and the streak and in-flight flag
+    leave the counts whole (each dispatch has one cause, the batcher ends
+    idle)."""
+    import sys
+
+    engine = FakeEngine()
+    callers, each = 16, 25
+    wrong = []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with DeviceScheduler() as sched, MicroBatcher(
+                engine, max_batch=4, window_ms=1.0, scheduler=sched) as b:
+            def caller(c):
+                for i in range(each):
+                    v = c * each + i
+                    out = b.submit(_img(v)).result(timeout=30)
+                    if out[0] != 4 * 4 * 3 * float(v):
+                        wrong.append(v)
+
+            threads = [threading.Thread(target=caller, args=(c,))
+                       for c in range(callers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    st = b.stats()
+    assert wrong == []
+    assert st["requests"] == callers * each
+    assert sum(st["dispatch_causes"].values()) == st["dispatches"]
+    assert st["queue_depth"] == 0 and not b._in_flight
+
+
+def test_scheduler_busy_while_a_job_runs_or_waits():
+    with DeviceScheduler() as sched:
+        assert not sched.busy()
+        gate, release = threading.Event(), threading.Event()
+
+        def hold():
+            gate.set()
+            release.wait(5)
+
+        holder = threading.Thread(
+            target=lambda: sched.run(hold, urgency=0.0))
+        holder.start()
+        assert gate.wait(5)
+        assert sched.busy() and sched.stats()["queued"] == 0  # running
+        waiter = threading.Thread(
+            target=lambda: sched.run(lambda: None, urgency=1.0))
+        waiter.start()
+        deadline = time.perf_counter() + 5
+        while sched.stats()["queued"] < 1 \
+                and time.perf_counter() < deadline:
+            time.sleep(0.001)
+        assert sched.busy()
+        release.set()
+        holder.join(5)
+        waiter.join(5)
+        assert not sched.busy()
 
 
 # ---------------------------------------------------------------------------
