@@ -133,7 +133,6 @@ def serve_and_check(server, network):
     import numpy as np
 
     from repro.core.engine import InferenceEngine
-    from repro.serving import xla_fallback_plan
 
     rep = {"network": network}
     t0 = time.perf_counter()
@@ -180,8 +179,7 @@ def serve_and_check(server, network):
     rep["concurrent_s"] = time.perf_counter() - t0
 
     # the plain reference: same weights, every conv on XLA, HIGHEST
-    ref_engine = InferenceEngine(cfg, params=engine.params,
-                                 plan=xla_fallback_plan(cfg))
+    ref_engine = InferenceEngine(cfg, params=engine.params, algorithm="xla")
     errs = []
     with jax.default_matmul_precision("highest"):
         for i, (img, got) in enumerate(zip(imgs, served)):

@@ -15,7 +15,8 @@ builds one plan per network (tune once — the paper's §2.3 argument that
 single-image inference amortizes per-shape tuning), saves it as JSON for
 tune-once/deploy-many, and threads ``plan.choices`` into the jitted forward
 so each layer dispatches to its tuned kernel with its tuned parameters.
-Results are memoized per (ConvSpec, mode).
+Results are memoized per (ConvSpec, mode). ``route`` is where any request
+(``"auto"``, ``"xla"`` or a kernel's name) becomes the Choice a site runs.
 """
 from __future__ import annotations
 
@@ -95,6 +96,23 @@ def tunable(spec: ConvSpec) -> bool:
     return spec.stride in (1, 2) and spec.r > 1 and spec.s > 1
 
 
+# dense kernels with a stride-2 variant; im2col, libdnn and winograd run
+# at stride 1 only
+STRIDED_DENSE = ("ilpm", "direct")
+
+
+def dense_fits(algorithm: str, spec: ConvSpec) -> bool:
+    """Whether a dense kernel runs ``spec`` as it is: off stride 1 only
+    the ``STRIDED_DENSE`` kernels do, and Winograd F(2,3) takes a 3x3
+    filter over an even output. The tuner costs no dense candidate that
+    fails this, and ``route`` sends a request that fails it to ilpm."""
+    if spec.stride != 1 and algorithm not in STRIDED_DENSE:
+        return False
+    return algorithm != "winograd" or (
+        (spec.r, spec.s) == (3, 3) and spec.out_h % 2 == 0
+        and spec.out_w % 2 == 0)
+
+
 def xla_choice(spec: ConvSpec, *, peak_flops=PEAK_FLOPS,
                hbm_bw=HBM_BW, epilogue=False) -> Choice:
     """Roofline estimate for the XLA escape-hatch path (untiled model).
@@ -117,7 +135,8 @@ def _candidates(spec: ConvSpec, epilogue=False):
     """Enumerate (algorithm, params, hbm_bytes, flops, vmem_working_set).
 
     Strided specs (stride 2) enumerate only the families whose kernels
-    downsample in-kernel: ilpm/direct for spatial, pointwise for 1x1,
+    downsample in-kernel: ilpm/direct for spatial (``dense_fits``, which
+    also keeps Winograd to the 3x3 sites it fits), pointwise for 1x1,
     depthwise for grouped. ``epilogue=True`` adds the fused scale/bias
     loads (2·K elements — noise, but kept honest) to every candidate; the
     *unfused* penalty is charged to `xla_choice`, not here, since every
@@ -190,10 +209,6 @@ def _candidates(spec: ConvSpec, epilogue=False):
         if th == H:
             break
 
-    if stride != 1:
-        # im2col / libdnn / winograd have no strided kernels
-        return cands
-
     # --- im2col: patch matrix round-trips HBM (the paper's 14.6x enemy);
     # its two-phase structure can't fuse the epilogue either, so it pays
     # the full unfused output round-trip, not the ~free vector loads ---
@@ -217,16 +232,15 @@ def _candidates(spec: ConvSpec, epilogue=False):
             break
 
     # --- winograd F(2,3): 2.25x fewer MACs, 4x transform traffic ---
-    if (R, S) == (3, 3) and H % 2 == 0 and W % 2 == 0:
-        v_bytes = B * 16 * (H // 2) * (W // 2) * C * el
-        m_bytes = B * 16 * (H // 2) * (W // 2) * K * el
-        traffic = img + v_bytes + v_bytes + 16 * C * K * el + m_bytes \
-            + m_bytes + out + ep
-        flops = 2 * B * 16 * (H // 2) * (W // 2) * C * K  # the 16 GEMMs
-        vmem = (img // max(B, 1)) + 16 * C * K * el \
-            + min((H // 2) * (W // 2), 512) * (C + K) * el
-        cands.append(("winograd", (), traffic, flops, vmem))
-    return cands
+    v_bytes = B * 16 * (H // 2) * (W // 2) * C * el
+    m_bytes = B * 16 * (H // 2) * (W // 2) * K * el
+    traffic = img + v_bytes + v_bytes + 16 * C * K * el + m_bytes \
+        + m_bytes + out + ep
+    flops = 2 * B * 16 * (H // 2) * (W // 2) * C * K  # the 16 GEMMs
+    vmem = (img // max(B, 1)) + 16 * C * K * el \
+        + min((H // 2) * (W // 2), 512) * (C + K) * el
+    cands.append(("winograd", (), traffic, flops, vmem))
+    return [c for c in cands if dense_fits(c[0], spec)]
 
 
 def cost_model_select(spec: ConvSpec, *, peak_flops=PEAK_FLOPS, hbm_bw=HBM_BW,
@@ -577,22 +591,48 @@ def _spec_dict(spec) -> dict:
             if k not in _SE_FIELDS or v}
 
 
-def xla_fallback_plan(named_specs, mode: str = "cost_model") -> TuningPlan:
-    """Every site on the ``xla`` escape hatch — the **degraded-mode**
-    plan the serving layer deploys when tuned Pallas dispatch (or a
-    block-plan deploy) fails persistently.
+def route(spec: ConvSpec, algorithm: str, *, epilogue=False) -> Choice:
+    """The kernel, with its parameters, that runs a conv site of ``spec``
+    when a caller asks for ``algorithm``: the one place a request becomes
+    a kernel.
 
-    Each enumerated site gets ``xla_choice`` (costed as the fused
-    conv+BN+act variant, matching how engines tune), and no block sites
-    are fused — the forward routes every conv through the lax reference
-    path, trading the paper's tuned kernels for staying up. Geometry and
-    dtype come from the same ``named_specs`` enumeration a tuned plan
-    uses, so engine plan-validation accepts the fallback unchanged.
+    ``"auto"`` is the tuner's pick (``select``), ``"xla"`` the escape
+    hatch. A kernel named outright runs with its default parameters where
+    it applies; elsewhere the site goes to one that does: a grouped site
+    not asked for a depthwise kernel that fits it to xla, and pointwise on
+    a spatial filter or a dense kernel that ``dense_fits`` refuses to
+    ilpm. Such a Choice is costed at the site's compulsory traffic and
+    FLOPs, having no tuned tiling to cost.
     """
-    plan = TuningPlan(mode=mode)
+    if algorithm == "auto":
+        return select(spec, epilogue=epilogue)
+    if spec.groups > 1:
+        if algorithm != "depthwise" or not tunable(spec):
+            algorithm = "xla"
+    elif algorithm == "pointwise":
+        if (spec.r, spec.s) != (1, 1):
+            algorithm = "ilpm"
+    elif algorithm != "xla" and not dense_fits(algorithm, spec):
+        algorithm = "ilpm"
+    if algorithm == "xla":
+        return xla_choice(spec, epilogue=epilogue)
+    t = max(spec.flops / PEAK_FLOPS, spec.bytes_min / HBM_BW)
+    return Choice(algorithm, (), t, spec.bytes_min, spec.flops, 0)
+
+
+def forced_plan(named_specs, algorithm: str) -> TuningPlan:
+    """Every site on ``route(spec, algorithm)``, costed as the fused
+    conv+BN+act variant the forwards run, and no fused blocks: the plan of
+    an engine asked for one algorithm. With ``"xla"`` it is the
+    **degraded-mode** plan the serving layer deploys when tuned Pallas
+    dispatch (or a block-plan deploy) fails persistently. Geometry and
+    dtype come from the same ``named_specs`` enumeration a tuned plan
+    uses, so engine plan-validation accepts it unchanged.
+    """
+    plan = TuningPlan()
     for name, spec in named_specs:
         plan.specs[name] = spec
-        plan.choices[name] = xla_choice(spec, epilogue=True)
+        plan.choices[name] = route(spec, algorithm, epilogue=True)
     return plan
 
 

@@ -68,9 +68,10 @@ class InferenceEngine:
     """Tune-once, run-many single-image inference.
 
     ``algorithm="auto"`` tunes a per-layer plan (``tune_mode`` picks
-    cost-model vs measured); a concrete algorithm name forces every 3x3
-    conv onto that algorithm; ``plan=`` (a TuningPlan or a JSON path)
-    skips tuning and deploys a saved plan.
+    cost-model vs measured); a concrete algorithm name (or ``"xla"``)
+    builds a plan whose every site is ``autotune.route`` of that name,
+    with no fused blocks; ``plan=`` (a TuningPlan or a JSON path) skips
+    tuning and deploys a saved plan. The engine always holds a plan.
     """
 
     def __init__(self, cfg, params=None, seed=0, algorithm="auto",
@@ -87,12 +88,14 @@ class InferenceEngine:
             self._validate_plan(plan)
         elif algorithm == "auto":
             plan = self.tune(mode=tune_mode)
+        else:
+            plan = autotune.forced_plan(self._conv_specs(), algorithm)
         self.plan = plan
-        self.reports = self._reports_from_plan(plan) if plan else []
+        self.reports = self._reports_from_plan(plan)
         # Winograd filter transforms U = G g G^T are constant at inference
         # (weights frozen): compute each winograd site's U once now, not
         # per forward, and thread the cache into the jitted forward.
-        self.winograd_u = self._winograd_cache(plan) if plan else {}
+        self.winograd_u = self._winograd_cache(plan)
         # winograd_u rides as a jit *argument* (a pytree, like params),
         # not a closure constant: baked-in constants would be re-embedded
         # into every trace of every entry point below.
@@ -102,9 +105,9 @@ class InferenceEngine:
         # dispatches the block megakernel and skips the constituent convs'
         # entries entirely.
         fwd1 = functools.partial(
-            self._model.forward, cfg=cfg, algorithm=algorithm,
-            plan={**plan.choices, **plan.block_choices}
-            if plan is not None else None)
+            self._model.forward, cfg=cfg,
+            plan={**self._unplanned(plan), **plan.choices,
+                  **plan.block_choices})
 
         # Named, so that a profiler trace names the device programs. Each
         # entry is the one program of its dispatch: the images are stacked
@@ -205,6 +208,14 @@ class InferenceEngine:
             cache[name] = _ref.winograd_filter_transform(w).astype(w.dtype)
         return cache
 
+    def _unplanned(self, plan: TuningPlan) -> dict:
+        """``autotune.route`` of the engine's ``algorithm`` at each conv
+        site ``plan`` has no entry for: what a partial plan's missing
+        sites run (``_validate_plan`` warns of them)."""
+        return {name: autotune.route(spec, self.algorithm, epilogue=True)
+                for name, spec in self._conv_specs()
+                if name not in plan.choices}
+
     def _validate_plan(self, plan: TuningPlan) -> None:
         """A deployed plan must match this network's conv geometry *and*
         precision — ConvSpec carries ``dtype``, so a plan tuned in fp32
@@ -225,8 +236,8 @@ class InferenceEngine:
         if missing or extra:
             logging.getLogger(__name__).warning(
                 "tuning plan coverage mismatch: missing=%s (these layers "
-                "fall back to untuned dispatch) extra=%s (ignored)",
-                sorted(missing), sorted(extra))
+                "run the engine's %r route) extra=%s (ignored)",
+                sorted(missing), self.algorithm, sorted(extra))
         # Block sites: intersection-only (a plan with no/fewer fused sites
         # just runs per-layer there — fusion is an optimization, never a
         # coverage obligation), but a present block entry must match this
@@ -241,7 +252,6 @@ class InferenceEngine:
                 f"mismatched block specs for {sorted(bad_blocks)}")
 
     def save_plan(self, path) -> None:
-        assert self.plan is not None, "engine has no plan to save"
         self.plan.save(path)
 
     @staticmethod
@@ -319,9 +329,6 @@ class InferenceEngine:
         gate scales (``gated_sites``) — integers for the ``engine.build``
         span."""
         plan = self.plan
-        if plan is None:
-            return {"sites": 0, "xla_sites": 0, "fused_sites": 0,
-                    "gated_sites": 0}
         return {"sites": len(plan.choices),
                 "xla_sites": sum(c.algorithm == "xla"
                                  for c in plan.choices.values()),

@@ -191,12 +191,10 @@ DENSE_ALGORITHMS = ("ilpm", "direct", "im2col", "libdnn", "winograd")
 def kernel_params(algorithm: str, params: dict) -> dict:
     """Keep only the params this algorithm's wrapper accepts.
 
-    The filter is what lets callers pass a superset of parameters — a
-    tuned ``block_k`` plus call-site geometry like ``stride`` — to any
-    algorithm: each wrapper receives exactly the keywords in its
-    signature and the rest are dropped silently. A wrapper declaring
-    ``**kwargs`` opts out of filtering and receives everything (the test
-    suite's spy wrappers rely on this).
+    It drops the call-site geometry, fused-epilogue and Winograd (``u``)
+    keywords a wrapper does not declare: ``stride`` for the stride-1-only
+    kernels, say. A wrapper declaring ``**kwargs`` opts out of filtering
+    and receives everything (the test suite's spy wrappers rely on this).
     """
     import inspect
 
@@ -211,18 +209,16 @@ def dispatch(algorithm: str, x_padded, w, *, impl="auto", **params):
     """Run one algorithm by name with its tuned kernel parameters.
 
     This is the single funnel every planned conv site goes through: the
-    engine's jitted forward calls it with the layer's tuned algorithm
-    name and ``Choice.params``. Semantics:
+    engine's jitted forward calls it with the layer's planned algorithm
+    name and ``Choice.params``, and runs that kernel; which kernel fits
+    a site was decided before (``repro.core.autotune.route``). Semantics:
 
       * ``ALGORITHMS`` is looked up at *call time*, so tests can spy on
         (or stub out) entries after import;
-      * ``params`` are filtered per-algorithm by ``kernel_params`` — a
-        plan tuned for one algorithm stays usable if dispatch falls back
-        to another whose kernel takes different knobs. The same filter
-        carries call-site geometry (``stride``), the fused epilogue
-        (``scale``/``bias``/``act`` — every conv wrapper accepts these)
-        and the cached Winograd transform (``u`` — winograd only, dropped
-        elsewhere);
+      * ``params`` are the Choice's tuned knobs plus call-site geometry
+        (``stride``), the fused epilogue (``scale``/``bias``/``act`` —
+        every conv wrapper accepts these) and the squeeze-and-excitation
+        and Winograd operands, filtered per wrapper by ``kernel_params``;
       * ``impl`` selects pallas vs jnp per the module policy above; the
         algorithm itself never changes with ``impl``, only its backend.
 

@@ -20,18 +20,25 @@ def vit_patch_specs(cfg, patch=14, in_ch=3):
             "b": ParamSpec((cfg.d_model,), (None,), "zeros")}
 
 
-def vit_patch_embed(p, cfg, images, patch=14, algorithm="ilpm"):
-    """images: (B,H,W,3) -> (B, n_patches, d_model) via stride-`patch` conv.
+def patch_conv(images, w, patch):
+    """Stride-``patch`` VALID ``patch``x``patch`` conv, (B,H,W,C) ->
+    (B, H//patch, W//patch, K): non-overlapping windows make it the
+    degenerate ILP-M case — a single "tap block", i.e. reshape + matmul,
+    K on lanes."""
+    B, H, W, C = images.shape
+    K = w.shape[-1]
+    hp, wp = H // patch, W // patch
+    xr = images[:, :hp * patch, :wp * patch].reshape(
+        B, hp, patch, wp, patch, C).transpose(0, 1, 3, 2, 4, 5)
+    xr = xr.reshape(B, hp * wp, patch * patch * C)
+    y = jnp.einsum("bpc,ck->bpk", xr, w.reshape(-1, K))
+    return y.reshape(B, hp, wp, K)
 
-    A stride-p pxp conv is exactly a non-overlapping patch unroll + matmul —
-    routed through the ILP-M conv engine (the paper's technique) when
-    requested; the engine will pick its unit-stride path or the blocked
-    matmul equivalent.
-    """
-    from repro.core import algorithms
 
-    y = algorithms.conv2d(images, p["w"], stride=patch, padding="VALID",
-                          algorithm=algorithm)
+def vit_patch_embed(p, cfg, images, patch=14):
+    """images: (B,H,W,3) -> (B, n_patches, d_model) via the stride-`patch`
+    conv ``patch_conv``."""
+    y = patch_conv(images, p["w"], patch)
     B, Hp, Wp, C = y.shape
     return (y + p["b"]).reshape(B, Hp * Wp, C)
 

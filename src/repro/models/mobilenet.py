@@ -162,21 +162,18 @@ def _se_gate(p, sums, pixels, act):
     return apply_act(r @ p["se_e"]["w"] + p["se_e"]["b"], "sigmoid")
 
 
-def forward(params, cfg, images, *, algorithm="auto", plan=None,
-            winograd_u=None):
+def forward(params, cfg, images, *, plan, winograd_u=None):
     """images: (B,H,W,3) NHWC -> logits (B, classes); a single unbatched
     (H,W,3) image maps to (classes,) — same batch-dim tolerance as
     ``resnet.forward``, so the forward is mappable per element.
 
-    `plan` maps layer names ("stem", "s0b0.dw", "s1b0.pw1", ...) to
-    autotuner `Choice`s, same contract as ``resnet.forward``: a planned
-    layer dispatches to its tuned algorithm with its tuned kernel params,
-    overriding `algorithm`; `winograd_u` carries cached Winograd filter
-    transforms per layer name. Plan lookup is trace-time Python, so a
-    jitted forward bakes in per-layer dispatch. Activations (ReLU6 or
-    swish) are fused into each conv's epilogue; projection convs are
-    linear. The strided dense stem runs the strided ilpm/direct kernels
-    under the tuner, not the XLA escape hatch.
+    ``plan`` maps every conv site's name ("stem", "s0b0.dw", "s1b0.pw1",
+    ...) to the ``Choice`` it runs, same contract as ``resnet.forward``:
+    a site without an entry is a ``KeyError``; ``winograd_u`` carries
+    cached Winograd filter transforms per layer name. Plan lookup is
+    trace-time Python, so a jitted forward bakes in per-layer dispatch.
+    Activations (ReLU6 or swish) are fused into each conv's epilogue;
+    projection convs are linear.
 
     A ``<block>.block`` plan entry (from ``build_plan(block_specs=...)``)
     overrides the block's 2-3 per-conv entries: the whole inverted
@@ -191,11 +188,9 @@ def forward(params, cfg, images, *, algorithm="auto", plan=None,
     if single:
         images = images[None]
     images = images.astype(cfg.dtype)  # compute precision is cfg.dtype
-    plan = plan or {}
     wu = winograd_u or {}
     act = _act(cfg)
-    x = _conv(params["stem"], images, 2, algorithm, "stem", plan, wu,
-              act=act)
+    x = _conv(params["stem"], images, 2, "stem", plan, wu, act=act)
     for name, cin, mid, cout, stride, _, se in _blocks(cfg):
         p = params[name]
         residual = stride == 1 and cin == cout
@@ -207,23 +202,20 @@ def forward(params, cfg, images, *, algorithm="auto", plan=None,
             continue
         h = x
         if "pw1" in p:
-            h = _conv(p["pw1"], h, 1, algorithm, f"{name}.pw1", plan,
-                      act=act)
+            h = _conv(p["pw1"], h, 1, f"{name}.pw1", plan, act=act)
         if se:
-            h, sums = _conv(p["dw"], h, stride, algorithm, f"{name}.dw",
-                            plan, act=act, pool=True)
+            h, sums = _conv(p["dw"], h, stride, f"{name}.dw", plan,
+                            act=act, pool=True)
             with jax.named_scope(f"{name}.se"):
                 gate = _se_gate(p, sums, h.shape[1] * h.shape[2], act)
-            h = _conv(p["pw2"], h, 1, algorithm, f"{name}.pw2", plan,
-                      gate=gate)
+            h = _conv(p["pw2"], h, 1, f"{name}.pw2", plan, gate=gate)
         else:
-            h = _conv(p["dw"], h, stride, algorithm, f"{name}.dw", plan,
-                      act=act)
-            h = _conv(p["pw2"], h, 1, algorithm, f"{name}.pw2", plan)
+            h = _conv(p["dw"], h, stride, f"{name}.dw", plan, act=act)
+            h = _conv(p["pw2"], h, 1, f"{name}.pw2", plan)
         if residual:
             h = h + x
         x = h
-    x = _conv(params["head"], x, 1, algorithm, "head", plan, act=act)
+    x = _conv(params["head"], x, 1, "head", plan, act=act)
     x = x.mean(axis=(1, 2))
     logits = x @ params["fc"]["w"] + params["fc"]["b"]
     return logits[0] if single else logits

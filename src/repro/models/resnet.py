@@ -144,22 +144,22 @@ def block_specs(cfg):
     return specs
 
 
-def _conv(p, x, stride, algorithm, site, plan, wu=None, act=None, **se):
-    """One conv site, under a named scope of its plan name ``site``:
-    folded-BN scale/bias and the activation ride into the kernel as a
-    fused epilogue (``algorithms.conv2d`` threads them to the dispatched
-    kernel's output write). ``se`` carries a squeeze-and-excitation
+def _conv(p, x, stride, site, plan, wu=None, act=None, **se):
+    """One conv site, under a named scope of its plan name ``site``: runs
+    ``plan[site]``, its folded-BN scale/bias and activation riding into
+    the kernel as a fused epilogue (``algorithms.conv2d`` threads them to
+    the kernel's output write). ``se`` carries a squeeze-and-excitation
     block's ``pool`` / ``gate`` operands to ``conv2d``."""
     from repro.core import algorithms
 
     with jax.named_scope(site):
         return algorithms.conv2d(x, p["w"], stride=stride, padding="SAME",
-                                 algorithm=algorithm, choice=plan.get(site),
-                                 scale=p["scale"], bias=p["bias"], act=act,
+                                 choice=plan[site], scale=p["scale"],
+                                 bias=p["bias"], act=act,
                                  u=(wu or {}).get(site), **se)
 
 
-def _block(p, x, bottleneck, stride, algorithm, name="", plan=None, wu=None):
+def _block(p, x, bottleneck, stride, name, plan, wu=None):
     """A ``<name>.block`` plan entry replaces the block's final conv AND
     the shortcut add + outer ReLU with one fused dispatch (see
     ``algorithms.block_residual_conv``), under a named scope of that
@@ -167,41 +167,35 @@ def _block(p, x, bottleneck, stride, algorithm, name="", plan=None, wu=None):
     by a separate XLA add/ReLU pass."""
     from repro.core import algorithms
 
-    plan = plan or {}
     idn = x
     if "proj" in p:
-        idn = _conv(p["proj"], x, stride, algorithm, f"{name}.proj", plan)
+        idn = _conv(p["proj"], x, stride, f"{name}.proj", plan)
     bch = plan.get(f"{name}.block")
     if bottleneck:
-        h = _conv(p["c1"], x, 1, algorithm, f"{name}.c1", plan, act="relu")
-        h = _conv(p["c2"], h, stride, algorithm, f"{name}.c2", plan, wu,
-                  act="relu")
+        h = _conv(p["c1"], x, 1, f"{name}.c1", plan, act="relu")
+        h = _conv(p["c2"], h, stride, f"{name}.c2", plan, wu, act="relu")
         tail = "c3"
     else:
-        h = _conv(p["c1"], x, stride, algorithm, f"{name}.c1", plan, wu,
-                  act="relu")
+        h = _conv(p["c1"], x, stride, f"{name}.c1", plan, wu, act="relu")
         tail = "c2"
     if bch is not None:
         with jax.named_scope(f"{name}.block"):
             return algorithms.block_residual_conv(h, p[tail], bch, res=idn)
-    h = _conv(p[tail], h, 1, algorithm, f"{name}.{tail}", plan, wu)
+    h = _conv(p[tail], h, 1, f"{name}.{tail}", plan, wu)
     return jax.nn.relu(h + idn)
 
 
-def forward(params, cfg, images, *, algorithm="ilpm", plan=None,
-            winograd_u=None):
+def forward(params, cfg, images, *, plan, winograd_u=None):
     """images: (B,H,W,3) NHWC -> logits (B, classes); a single unbatched
     (H,W,3) image maps to (classes,).
 
-    `algorithm` selects the conv algorithm for every conv site — the
-    paper's five contenders are all valid values (plus 'xla' reference);
-    1x1 sites degrade gracefully (pointwise/ilpm) and strided sites use
-    the strided ilpm/direct kernels. `plan` optionally maps layer names
-    ("stem", "s0b1.c2", "s1b0.proj", ...) to autotuner `Choice`s; a
-    planned layer dispatches to its tuned algorithm with its tuned kernel
-    parameters, overriding `algorithm`. `winograd_u` maps layer names to
-    cached filter transforms `U = G g Gᵀ` (computed once per engine build
-    — weights are frozen at inference). Plan lookup is trace-time Python,
+    ``plan`` maps every conv site's name ("stem", "s0b1.c2", "s1b0.proj",
+    ...) to the ``Choice`` it runs — its algorithm with its kernel
+    parameters, as the engine's plan gives it — and may add
+    ``<block>.block`` entries for fused block tails; a site without an
+    entry is a ``KeyError``. ``winograd_u`` maps layer names to cached
+    filter transforms ``U = G g Gᵀ`` (computed once per engine build —
+    weights are frozen at inference). Plan lookup is trace-time Python,
     so a jitted forward bakes in per-layer dispatch.
 
     Batch-dim tolerance makes the forward mappable per element: under
@@ -213,19 +207,17 @@ def forward(params, cfg, images, *, algorithm="ilpm", plan=None,
     if single:
         images = images[None]
     images = images.astype(cfg.dtype)  # compute precision is cfg.dtype
-    plan = plan or {}
     wu = winograd_u or {}
     blocks = cfg.extra["blocks"]
     bottleneck = cfg.extra["bottleneck"]
-    x = _conv(params["stem"], images, 2, algorithm, "stem", plan, wu,
-              act="relu")
+    x = _conv(params["stem"], images, 2, "stem", plan, wu, act="relu")
     x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 3, 3, 1),
                               (1, 2, 2, 1), "SAME")
     for si, n in enumerate(blocks):
         for bi in range(n):
             stride = 2 if (si > 0 and bi == 0) else 1
             x = _block(params[f"s{si}b{bi}"], x, bottleneck, stride,
-                       algorithm, name=f"s{si}b{bi}", plan=plan, wu=wu)
+                       f"s{si}b{bi}", plan, wu)
     x = x.mean(axis=(1, 2))
     logits = x @ params["fc"]["w"] + params["fc"]["b"]
     return logits[0] if single else logits

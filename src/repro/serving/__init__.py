@@ -37,7 +37,6 @@ from repro.serving.engine_cache import (  # noqa: F401
     EngineLease,
     engine_key,
     plan_key,
-    xla_fallback_plan,
 )
 from repro.serving.faults import Fault, FaultInjector  # noqa: F401
 from repro.serving.protocol import (  # noqa: F401
@@ -119,5 +118,4 @@ __all__ = [
     "read_frame",
     "spans",
     "unpack_body",
-    "xla_fallback_plan",
 ]

@@ -19,8 +19,9 @@ the engine's plan validation reject exactly that, so the key must match.
 
 Builds are fault-tolerant: a transient build failure retries with capped
 backoff, and a build that fails *persistently while deploying a cached
-plan* falls back to the xla-only plan (``xla_fallback_plan``) instead of
-failing every request for the key. ``degrade(cfg)`` is the same fallback
+plan* falls back to an engine built with ``algorithm="xla"`` (every conv
+site on the escape hatch: ``autotune.forced_plan``) instead of failing
+every request for the key. ``degrade(cfg)`` is the same fallback
 on demand — the batcher calls it when an engine's circuit breaker trips —
 and ``stats()`` counts both under ``degraded``.
 
@@ -44,16 +45,6 @@ from repro.serving import spans
 from repro.serving.resilience import RetryPolicy, TransientFailure
 
 log = logging.getLogger("repro.serving")
-
-
-def xla_fallback_plan(cfg):
-    """The degraded-mode plan for ``cfg``: every conv site on the xla
-    escape hatch, no fused blocks — same geometry/dtype enumeration as a
-    tuned plan, so engine plan-validation accepts it unchanged."""
-    from repro.core import autotune
-    from repro.models.registry import cnn_module
-
-    return autotune.xla_fallback_plan(cnn_module(cfg).conv_specs(cfg))
 
 
 def engine_key(cfg, device: str | None = None) -> tuple:
@@ -219,7 +210,7 @@ class EngineCache:
                         "plan deploy for %s failed persistently (%s); "
                         "rebuilding on the xla fallback plan", cfg.name, e)
                     return InferenceEngine(cfg, params=params, seed=seed,
-                                           plan=xla_fallback_plan(cfg)), True
+                                           algorithm="xla"), True
                 raise
 
     def degrade(self, cfg, *, params=None, seed: int = 0) -> InferenceEngine:
@@ -238,8 +229,7 @@ class EngineCache:
             old = self._engines.get(key)
         if params is None and old is not None:
             params = old.params
-        eng = InferenceEngine(cfg, params=params, seed=seed,
-                              plan=xla_fallback_plan(cfg))
+        eng = InferenceEngine(cfg, params=params, seed=seed, algorithm="xla")
         with self._lock:
             self._engines[key] = eng
             self._engines.move_to_end(key)

@@ -14,9 +14,10 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # not tuned kernel parameters — the spies drop them so recorded calls
 # compare cleanly against plan Choice.params. The block-level keys ride
 # along: residual/out_act (inverted residual geometry) and res (the
-# shortcut operand — a tensor, not a tunable).
+# shortcut operand — a tensor, not a tunable); so do the squeeze-and-
+# excitation operands pool/gate.
 NON_TUNED_KEYS = ("stride", "scale", "bias", "act", "u",
-                  "residual", "res", "out_act")
+                  "residual", "res", "out_act", "pool", "gate")
 
 
 def spy_algorithms(monkeypatch):

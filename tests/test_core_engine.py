@@ -47,9 +47,11 @@ def test_conv2d_dispatch(algorithm):
 
 def test_conv2d_patch_embed_path():
     """Stride-p VALID pxp conv == non-overlapping ILP-M degenerate case."""
+    from repro.models import frontends
+
     x = jax.random.normal(KEY, (1, 28, 28, 3))
     w = jax.random.normal(jax.random.fold_in(KEY, 2), (14, 14, 3, 32))
-    y = conv2d(x, w, stride=14, padding="VALID", algorithm="ilpm")
+    y = frontends.patch_conv(x, w, 14)
     gt = ref.conv2d_reference(x, w, stride=14, padding="VALID")
     assert y.shape == (1, 2, 2, 32)
     np.testing.assert_allclose(np.asarray(y), np.asarray(gt), rtol=2e-4,

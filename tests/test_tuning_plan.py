@@ -9,9 +9,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get, tiny_variant
-from repro.core import InferenceEngine, TuningPlan, build_plan
+from repro.core import InferenceEngine, TuningPlan, build_plan, conv2d, select
 from repro.core.autotune import (Choice, ConvSpec, cost_model_select,
-                                 measured_select)
+                                 measured_select, route)
 from conftest import spy_algorithms as _spy_algorithms
 from repro.kernels import ops
 
@@ -111,6 +111,84 @@ def test_engine_auto_plan_drives_dispatch(monkeypatch, tmp_path):
     assert sorted(calls) == expected
     np.testing.assert_allclose(np.asarray(logits2), np.asarray(logits),
                                rtol=1e-6, atol=1e-6)
+
+
+DENSE = dict(h=8, w=8, c=8, k=16)
+DW = dict(h=8, w=8, c=8, k=8, groups=8)
+
+
+@pytest.mark.parametrize("spec_kw, algorithm, want", [
+    (DENSE, "auto", "ilpm"),
+    (DENSE, "xla", "xla"),
+    (DENSE, "direct", "direct"),
+    (DENSE, "im2col", "im2col"),
+    ({**DENSE, "stride": 2}, "direct", "direct"),
+    (DENSE, "pointwise", "ilpm"),
+    ({**DENSE, "r": 1, "s": 1, "stride": 2}, "pointwise", "pointwise"),
+    ({**DENSE, "stride": 2}, "im2col", "ilpm"),
+    ({**DENSE, "stride": 2}, "libdnn", "ilpm"),
+    ({**DENSE, "stride": 2}, "winograd", "ilpm"),
+    (DENSE, "winograd", "winograd"),
+    ({**DENSE, "h": 7, "w": 7}, "winograd", "ilpm"),
+    ({**DENSE, "r": 5, "s": 5}, "winograd", "ilpm"),
+    ({**DW, "stride": 2}, "depthwise", "depthwise"),
+    (DW, "ilpm", "xla"),
+    (DW, "pointwise", "xla"),
+    ({**DENSE, "groups": 4}, "depthwise", "xla"),
+    ({**DW, "h": 9, "w": 9, "stride": 3}, "depthwise", "xla"),
+], ids=["auto", "xla", "dense_kept", "dense_kept_im2col",
+        "dense_kept_strided", "pointwise_on_3x3", "pointwise_strided_1x1",
+        "strided_im2col", "strided_libdnn", "strided_winograd",
+        "winograd_even", "winograd_odd_output", "winograd_on_5x5",
+        "depthwise_kept", "depthwise_asked_as_ilpm",
+        "depthwise_asked_as_pointwise", "grouped_non_depthwise",
+        "depthwise_at_stride_3"])
+def test_route(monkeypatch, spec_kw, algorithm, want):
+    """``route`` maps each request to the kernel that fits the site, and
+    ``conv2d(algorithm=...)`` runs exactly that kernel, with the kernel's
+    default parameters when it was named outright."""
+    spec = ConvSpec(**spec_kw)
+    choice = route(spec, algorithm)
+    assert choice.algorithm == want
+    if algorithm == "auto":
+        assert choice == select(spec)
+    elif want != "xla":
+        assert choice.params == ()
+    calls = _spy_algorithms(monkeypatch)
+    x = jax.random.normal(KEY, (1, spec.h, spec.w, spec.c))
+    w = jax.random.normal(KEY, (spec.r, spec.s, spec.c_per_group, spec.k))
+    conv2d(x, w, stride=spec.stride, algorithm=algorithm)
+    assert calls == ([] if want == "xla" else [(want, choice.params)])
+
+
+@pytest.mark.parametrize("net", ["resnet18", "mobilenet_v2",
+                                 "efficientnet_b0"])
+def test_tuned_forward_runs_each_planned_site_once(monkeypatch, net):
+    """The tuned forward makes exactly one kernel call per planned conv or
+    block site, with the plan's algorithm and parameters, and an engine
+    forced onto ``xla`` makes none."""
+    cfg = tiny_variant(get(net))
+    eng = InferenceEngine(cfg)
+    plan = eng.plan
+    fused = {f"{b[:-len('.block')]}.{sfx}" for b in plan.block_choices
+             for sfx, _ in plan.block_specs[b].conv_specs()}
+    want = sorted([(c.algorithm, c.params) for n, c in plan.choices.items()
+                   if n not in fused]
+                  + [(c.algorithm, c.params)
+                     for c in plan.block_choices.values()])
+    calls = _spy_algorithms(monkeypatch)
+    img = jax.random.normal(KEY, (32, 32, 3))
+    eng.run(img)
+    assert sorted(calls) == want
+    assert len(calls) == len(plan.choices) - len(fused) \
+        + len(plan.block_choices)
+
+    calls.clear()
+    xla = InferenceEngine(cfg, params=eng.params, algorithm="xla")
+    assert set(xla.plan.algorithms().values()) == {"xla"}
+    assert not xla.plan.block_choices
+    xla.run(img)
+    assert calls == []
 
 
 def test_plan_validation_rejects_wrong_network(tmp_path):
